@@ -43,6 +43,8 @@ from repro.obs.report import jsonable as _jsonable
 
 
 def main() -> None:
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     from benchmarks import (fig4_calibration, fig8_event_interface,
                             fig11_rstdp, step_time, faults_bench,
                             kernels_bench, mapper_bench, ppuvm_bench,

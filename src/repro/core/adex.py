@@ -29,11 +29,15 @@ class NeuronState(NamedTuple):
 
 def init_state(shape, params) -> NeuronState:
     # distinct buffers per leaf: a shared zeros array would alias leaves
-    # and break buffer donation of the whole state (donate-twice error)
+    # and break buffer donation of the whole state (donate-twice error).
+    # ``v`` is a copy for the same reason: when the shapes already match,
+    # broadcast_to/astype hand back the parameter's own buffer, and
+    # donating the state would then delete the instance's e_leak
     def z():
         return jnp.zeros(shape, jnp.float32)
-    return NeuronState(v=jnp.broadcast_to(params["e_leak"], shape).astype(jnp.float32),
-                       w=z(), i_exc=z(), i_inh=z(), refrac=z())
+    v = jnp.array(jnp.broadcast_to(params["e_leak"], shape),
+                  dtype=jnp.float32, copy=True)
+    return NeuronState(v=v, w=z(), i_exc=z(), i_inh=z(), refrac=z())
 
 
 SPIKE_CLAMP = 30.0   # mV above which the exponential term is clamped

@@ -48,6 +48,7 @@ jnp oracle elsewhere), ``pallas``, ``interpret``, or ``ref``.
 """
 from __future__ import annotations
 
+import copy
 from typing import Dict, NamedTuple, Optional
 
 import jax
@@ -135,6 +136,9 @@ class AnnCore:
         self.sparse_k_cap = sparse_k_cap
         self.telemetry = telemetry
         self.faults = faults
+        # membrane decay factors handed in by ``_run_per_device``; None
+        # derives them from ``inst`` where they are used
+        self._decays = None
 
     def init_state(self, prefix=()) -> AnnCoreState:
         cfg = self.cfg
@@ -283,8 +287,12 @@ class AnnCore:
         if telemetry is None and self.telemetry:
             telemetry = obs_trace.init_telemetry()
         ev, ad = router.merge(routed_ev, row_spikes_t, row_addr_t)
-        state, out = self.run(state, ev, ad, record_v=record_v,
-                              unroll=unroll, telemetry=telemetry)
+        if router._axis is not None and self._pallas_kernels():
+            state, out = self._run_per_device(router, state, ev, ad,
+                                              record_v, unroll, telemetry)
+        else:
+            state, out = self.run(state, ev, ad, record_v=record_v,
+                                  unroll=unroll, telemetry=telemetry)
         routed, tele = router.route(out["spikes"],
                                     out.get("telemetry", telemetry),
                                     routed_in=routed_ev)
@@ -292,6 +300,56 @@ class AnnCore:
         if tele is not None:
             out["telemetry"] = tele
         return state, out
+
+    def _pallas_kernels(self) -> bool:
+        """True when ``run`` calls the Pallas kernels (native on TPU, or
+        interpreted: the CPU rehearsal of the same program)."""
+        impl = self.kernel_impl
+        if impl == "auto":
+            impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+        return self.backend != "oracle" and impl in ("pallas", "interpret")
+
+    def _run_per_device(self, router, state, ev, ad, record_v, unroll,
+                        telemetry):
+        """``run`` under ``shard_map`` over the router's chip axis: each
+        device integrates only its own chips. The TPU compiler refuses to
+        partition a Pallas kernel across devices ("Mosaic kernels cannot
+        be automatically partitioned"), so a sharded wafer with Pallas
+        kernels needs this per-device program; chips are
+        independent within a window, so it computes what ``run`` does.
+        Telemetry counters and fault overlays are fleet-wide and are not
+        split per device here.
+
+        The membrane decay factors are computed outside the ``shard_map``.
+        On the local path the instance is a constant and XLA folds them on
+        the host; inside, each device would compute them from its slice,
+        and a TPU's exp and division round differently from the host's,
+        enough to flip spikes within a few windows."""
+        from jax.sharding import PartitionSpec as P
+        if telemetry is not None or self.faults is not None:
+            raise NotImplementedError(
+                "a sharded wafer with native kernels runs without "
+                "telemetry and fault overlays")
+        axis, K = router._axis, router.K
+        chips = lambda tree: jax.tree.map(        # noqa: E731
+            lambda x: P(axis) if x.ndim and x.shape[0] == K else P(), tree)
+        out_spec = dict(spikes=P(None, axis))
+        if record_v:
+            out_spec["v"] = P(None, axis)
+        decays = adex.decay_factors(self.inst["neuron_params"], self.cfg.dt)
+
+        def body(inst, dec, st, e, a):
+            local = copy.copy(self)
+            local.inst = inst
+            local._decays = dec
+            return local.run(st, e, a, record_v=record_v, unroll=unroll)
+
+        return jax.shard_map(
+            body, mesh=router._mesh,
+            in_specs=(chips(self.inst), chips(decays), chips(state),
+                      P(None, axis), P(None, axis)),
+            out_specs=(chips(state), out_spec), check_vma=False)(
+                self.inst, decays, state, ev, ad)
 
     def _run_oracle(self, state: AnnCoreState, row_spikes_t, row_addr_t,
                     record_v: bool = False, unroll: int = 1,
@@ -375,19 +433,22 @@ class AnnCore:
         kernel, packed-carry block scan on CPU). Returns
         ``(new_neuron, rate_counters, recs)``."""
         cfg = self.cfg
+        decays = self._decays
+        if decays is None:
+            decays = adex.decay_factors(self.inst["neuron_params"], cfg.dt)
         if self.backend == "blocked":
             from repro.kernels.neuron_scan import ops as neuron_ops
             return neuron_ops.neuron_window(
                 neuron, rate_counters, i_exc_t, i_inh_t,
                 self.inst["neuron_params"], dt=cfg.dt,
-                use_adex=cfg.neuron.adex, impl=self.kernel_impl,
-                block=self.block_size, trace_block=self.trace_block,
+                use_adex=cfg.neuron.adex, decays=decays,
+                impl=self.kernel_impl, block=self.block_size,
+                trace_block=self.trace_block,
                 kernel_block=self.kernel_block, record_v=record_v)
 
         # fused: O(C) per step with the time-invariant decay factors
         # hoisted out of the loop
         dt, inst = cfg.dt, self.inst
-        decays = adex.decay_factors(inst["neuron_params"], dt)
 
         def body(carry, xs):
             n, rc = carry

@@ -147,7 +147,8 @@ def make_experiment(cfg: BSS2Config = None, ecfg: RSTDPConfig = RSTDPConfig(),
     the monolithic key stream and then rearranged onto the chips, so the
     learning trajectory is bit-identical for every chip count — the
     closed-loop half of the split-vs-monolithic contract. ``wafer_ctx``
-    (a ``ShardingCtx``) turns on the shard_map link collectives;
+    (a ``ShardingCtx``) turns on the shard_map link collectives and
+    places each chip's state on its device;
     ``link_budget``/``link_mode`` are the router's bus-budget knobs.
 
     ``faults``: a ``repro.faults.FaultPlan`` (or sequence) injected into
@@ -303,11 +304,30 @@ def make_experiment(cfg: BSS2Config = None, ecfg: RSTDPConfig = RSTDPConfig(),
         st = core.init_state(prefix)
         w0 = ecfg.w_init * jnp.ones((*prefix, ecfg.n_inputs, c_loc))
         st = st._replace(syn=_write_signed(st.syn, w0))
-        return ExperimentState(
+        state = ExperimentState(
             core=st, w_signed=w0,
             mean_reward=jnp.zeros((*prefix, c_loc)), key=key,
             tele=obs_trace.init_telemetry() if telemetry else None,
             routed=router.init_buffer(ecfg.trial_steps) if K else None)
+        if K and wafer_ctx is not None and wafer_ctx.mesh is not None:
+            state = _place_chips(state)
+        return state
+
+    def _place_chips(state):
+        """Wafer mode on a mesh: every chip-major leaf ([K, ...]) and the
+        routed grid ([T, K, R]) get the instance sharding, so each device
+        holds its own emulated chips' state (the same axis the router's
+        link collectives run over)."""
+        def chip_major(x):
+            return jax.lax.with_sharding_constraint(
+                x, wafer_ctx.instance_sharding(x.shape))
+        placed = jax.tree.map(chip_major, (state.core, state.w_signed,
+                                           state.mean_reward))
+        routed = jax.lax.with_sharding_constraint(
+            state.routed, jax.sharding.NamedSharding(
+                wafer_ctx.mesh, wafer_ctx.link_specs(1, 3)[0]))
+        return state._replace(core=placed[0], w_signed=placed[1],
+                              mean_reward=placed[2], routed=routed)
 
     def _write_signed(syn, w_signed):
         w_exc = jnp.clip(w_signed, 0, None)
@@ -679,8 +699,6 @@ def lower_bss2_cell(shape, ctx, mesh_cfg):
 
     txt = compiled.as_text()
     ca = compiled.cost_analysis()
-    if isinstance(ca, list):     # older jax returns [dict] per computation
-        ca = ca[0] if ca else {}
     ma = compiled.memory_analysis()
     colls = parse_collectives(txt)
     hbm = hbm_bytes_estimate(txt)

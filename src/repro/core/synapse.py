@@ -131,8 +131,10 @@ def _sparse_window(weights, addresses, row_events_t, event_addr_t, gain,
     window fits the static capacities (overflow drops records; the
     ``sparse="auto"`` gate in ``synaptic_current_window`` guarantees the
     fit before routing here)."""
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    # the sparse kernel runs only as the explicit CPU cross-check: on TPU
+    # (kernel_impl "auto"/"pallas") this path is its jnp form, because the
+    # kernel's row gather does not lower there (synray_sparse/kernel.py)
+    impl = "interpret" if impl == "interpret" else "ref"
     from repro.kernels import (fold_instance, fold_instance_time,
                                unfold_instance_time)
     from repro.kernels.synray_sparse import ops as sparse_ops

@@ -1,8 +1,10 @@
 """Pallas TPU kernels for the machine model's compute hot-spots.
 
-Three kernels, each with a pure-jnp oracle (ref.py) and a jit'd wrapper
-(ops.py); validated shape/dtype-swept against the oracle in interpret mode
-(this container is CPU-only; TPU is the deployment target):
+Each kernel has a pure-jnp oracle (ref.py) and a jit'd wrapper (ops.py).
+On CPU the tests check them against the oracle in interpret mode; on a
+TPU they compile to Mosaic custom calls (``tests/test_tpu_compile.py``
+compiles each for a described v5e at 256x512 and 32x16, and
+``chip_smoke.py`` runs them on the chip):
 
   synray      event x 6-bit-weight synaptic-current matmul with in-kernel
               address matching (the synapse array's event path)
@@ -10,9 +12,14 @@ Three kernels, each with a pure-jnp oracle (ref.py) and a jit'd wrapper
               the event-sparse twin of synray: gather-accumulates only
               fired rows from a compact [T, K] record grid
               (repro.core.events) — O(T*K*C) instead of O(T*R*C), and
-              BIT-identical to the dense path (see its ref.py);
+              BIT-identical to the dense path on CPU (see its ref.py);
               auto-selected per window by measured event density in
-              ``synapse.synaptic_current_window(sparse="auto")``
+              ``synapse.synaptic_current_window(sparse="auto")``. Its
+              in-kernel row gather does not lower on TPU, so there the
+              sparse path runs the jnp form; the kernel runs only in
+              interpret mode, as a CPU cross-check
+  ppuvm_exec  the PPU-VM tile executor; interpret mode only (its register
+              file read does not lower on TPU, see its ops.py)
   corr        T-step fused correlation-sensor update: decay + outer-product
               accumulation entirely in VMEM (T x fewer HBM round trips)
   ppu_update  the PPU vector-unit inner loop: CADC digitization ->
@@ -24,7 +31,8 @@ Implementation selection
 Every ops.py wrapper takes ``impl``:
 
   auto        pallas when ``jax.default_backend() == "tpu"``, else ref
-  pallas      the native Pallas kernel (TPU)
+  pallas      the native Pallas kernel (TPU; synray_sparse and ppuvm_exec
+              raise)
   interpret   the Pallas kernel under the interpreter (CPU validation)
   ref         the module-level-jitted jnp oracle
 
@@ -54,6 +62,14 @@ from __future__ import annotations
 import math
 
 import jax.numpy as jnp
+
+
+def tile(block: int, dim: int) -> int:
+    """The requested block when it tiles ``dim``, else the whole dim. A
+    block equal to the array dim satisfies the TPU tiling rule (last two
+    block dims divisible by 8 and 128, or equal to the array's) at any
+    size, so e.g. the 16-column paper chip runs as one lane block."""
+    return block if block < dim and dim % block == 0 else dim
 
 
 def fold_instance(x, n_core: int):

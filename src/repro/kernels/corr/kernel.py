@@ -14,6 +14,13 @@ HBM every step. The TPU-native version tiles [R, C] into VMEM blocks and
 replays the whole T-window per tile — T x fewer HBM round trips; the spike
 vectors ([T, rb] + [T, cb]) are tiny. The in-kernel loop preserves per-step
 saturation semantics exactly (a post-hoc matmul over time would not).
+
+Mosaic layout: the loop reads step ``t``'s spike rows from the refs
+(``pre_ref[0, pl.ds(t, 1), :]``: a dynamic index into a loaded value
+lowers to ``dynamic_slice``, which the TPU compiler refuses), traces are
+[1, n] rows, and the row-side factors of the two outer products become
+[rb, 1] columns by a 2-D transpose. Row blocks are the whole R or a
+multiple of 128 (the lane rule for the [T, rb] spike block).
 """
 from __future__ import annotations
 
@@ -23,39 +30,41 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels import tile
+
 
 def _kernel(pre_ref, post_ref, tp0_ref, tq0_ref, ac0_ref, aa0_ref,
             ac_ref, aa_ref, tp_ref, tq_ref, *, lam: float, sat: float):
-    pre = pre_ref[0].astype(jnp.float32)       # [T, rb]
-    post = post_ref[0].astype(jnp.float32)     # [T, cb]
-    T = pre.shape[0]
+    T = pre_ref.shape[1]
 
     def body(t, carry):
-        tp, tq, ac, aa = carry
-        p_t = pre[t]
-        q_t = post[t]
+        tp, tq, ac, aa = carry                  # tp [1, rb], tq [1, cb]
+        # step rows are read from the refs (a dynamic sublane load), and
+        # the row-side vectors become [rb, 1] columns by a 2-D transpose
+        p_t = pre_ref[0, pl.ds(t, 1), :].astype(jnp.float32)
+        q_t = post_ref[0, pl.ds(t, 1), :].astype(jnp.float32)
         tp = tp * lam + p_t
         tq = tq * lam + q_t
-        ac = jnp.minimum(ac + tp[:, None] * q_t[None, :], sat)
-        aa = jnp.minimum(aa + p_t[:, None] * tq[None, :], sat)
+        ac = jnp.minimum(ac + jnp.transpose(tp) * q_t, sat)
+        aa = jnp.minimum(aa + jnp.transpose(p_t) * tq, sat)
         return tp, tq, ac, aa
 
-    tp0 = tp0_ref[0].astype(jnp.float32)[0]
-    tq0 = tq0_ref[0].astype(jnp.float32)[0]
+    tp0 = tp0_ref[0].astype(jnp.float32)
+    tq0 = tq0_ref[0].astype(jnp.float32)
     ac0 = ac0_ref[0].astype(jnp.float32)
     aa0 = aa0_ref[0].astype(jnp.float32)
     tp, tq, ac, aa = jax.lax.fori_loop(0, T, body, (tp0, tq0, ac0, aa0))
     ac_ref[0] = ac
     aa_ref[0] = aa
-    tp_ref[0] = tp[None]
-    tq_ref[0] = tq[None]
+    tp_ref[0] = tp
+    tq_ref[0] = tq
 
 
 @functools.partial(jax.jit,
                    static_argnames=("lam", "sat", "rb", "cb", "interpret"))
 def correlation_window_pallas(pre, post, tp0, tq0, ac0, aa0, *,
                               lam: float, sat: float = 1023.0,
-                              rb: int = 64, cb: int = 128,
+                              rb: int = 128, cb: int = 128,
                               interpret: bool = False):
     """pre: [N, T, R]; post: [N, T, C]; tp0 [N, R]; tq0 [N, C]; ac0/aa0
     [N, R, C] — the leading N is the instance grid axis (see
@@ -69,9 +78,8 @@ def correlation_window_pallas(pre, post, tp0, tq0, ac0, aa0, *,
         ac0, aa0 = ac0[None], aa0[None]
     N, T, R = pre.shape
     C = post.shape[-1]
-    rb = min(rb, R)
-    cb = min(cb, C)
-    assert R % rb == 0 and C % cb == 0
+    rb = tile(rb, R)
+    cb = tile(cb, C)
     grid = (N, R // rb, C // cb)
     acc_spec = pl.BlockSpec((1, rb, cb), lambda n, i, j: (n, i, j))
     row_spec = pl.BlockSpec((1, 1, rb), lambda n, i, j: (n, 0, i))

@@ -9,6 +9,14 @@ instance prefix is folded by nested vmap like the other kernel wrappers.
 Host-side preparation mirrors ``interp.prepare_operands`` bit-for-bit
 (rate saturation, Q8.8 digitization conventions), so the kernel consumes
 exactly the integers every other executor sees.
+
+TPU: the kernel does not compile for the chip. The shared ``step_word``
+indexes the register file by a traced register number
+(``regs[ra % N_REGS]``), and the TPU compiler refuses it: "Unimplemented
+primitive in Pallas TPU lowering for KernelType.TC: dynamic_slice" (v5e,
+at 256x512 and 32x16). So only interpret mode runs, as a CPU
+cross-check in the differential fuzz; the native call raises with that
+reason. The trace-time specializer is the executor for the chip.
 """
 from __future__ import annotations
 
@@ -25,6 +33,13 @@ def run_program_tiled(words, weights, qc, qa, rates, mod=None, noise=None,
                       interpret: bool = False):
     """Same signature/returns as ``interp.run_program_jax``:
     (weights_out int32 [..., R, C], regs int32 [N_REGS, ..., R, C])."""
+    if not interpret:
+        raise NotImplementedError(
+            "the ppuvm_exec kernel does not lower on TPU: the register-file "
+            "read regs[ra % N_REGS] is a dynamic_slice, 'Unimplemented "
+            "primitive in Pallas TPU lowering for KernelType.TC: "
+            "dynamic_slice'; use executor='specialized' (or "
+            "'pallas_interpret' on CPU)")
     lane_shape = weights.shape
     words = jnp.asarray(words, jnp.int32)
     weights = weights.astype(jnp.int32)

@@ -22,9 +22,15 @@ reduction and break the bit contract. The leading ``n`` is the instance
 grid axis shared with the other kernels (see ``repro.kernels``); 2-D
 record operands are promoted to N=1.
 
-Like the corr kernel, the in-kernel dynamic row gather targets TPU Mosaic
-only nominally — the verified path in this CPU container is interpret
-mode (tests/test_sparse.py), the deployment target compiles natively.
+TPU: this kernel does NOT compile for the chip. Mosaic refuses the
+in-kernel ``jnp.take`` row gather ("Shape mismatch in input, indices and
+output", v5e, at 256x512 and 32x16 alike). On TPU the sparse path
+therefore runs its jnp form (``ref.py``: an XLA gather and a
+HIGHEST-precision einsum) — decided by name in ``ops.sparse_window``,
+where ``impl="pallas"`` raises. The kernel stays as the interpret-mode
+cross-check of the gather-accumulate contract on CPU
+(tests/test_sparse.py). Lowering the gather with scalar-prefetched row
+indices is the open alternative (ROADMAP, Speed).
 """
 from __future__ import annotations
 

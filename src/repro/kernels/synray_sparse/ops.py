@@ -37,11 +37,17 @@ _ref_vmap_jit = jax.jit(jax.vmap(sparse_window_ref))
 
 def sparse_window(rows_tk, addr_tk, eff_tk, weights, addresses,
                   impl: str = "auto", **block_kw):
-    """impl: auto | pallas | interpret | ref. Record operands [.., T, K],
-    weights/addresses [.., R, C] (2-D = single instance)."""
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
-    if impl == "ref":
+    """impl: auto | interpret | ref. Record operands [.., T, K],
+    weights/addresses [.., R, C] (2-D = single instance).
+
+    ``auto`` is the jnp form on every backend: the kernel's row gather
+    does not lower on TPU (see ``kernel.py``), so ``pallas`` raises."""
+    if impl == "pallas":
+        raise NotImplementedError(
+            "synray_sparse has no native TPU lowering: Mosaic refuses the "
+            "in-kernel row gather ('Shape mismatch in input, indices and "
+            "output'); TPU runs the sparse path's jnp form (impl='ref')")
+    if impl in ("auto", "ref"):
         if rows_tk.ndim == 2:
             return _ref_jit(rows_tk, addr_tk, eff_tk, weights, addresses)
         return _ref_vmap_jit(rows_tk, addr_tk, eff_tk, weights, addresses)

@@ -16,7 +16,14 @@ long as the reduction runs through the same dot machinery. Hence the
 einsum below, never a hand-rolled accumulation loop (separate mul+add
 rounds differently than the fused multiply-add). Asserted exactly, over a
 0%..100% density sweep, in tests/test_sparse.py.
+
+This is also the sparse path's form on TPU (the Pallas twin does not
+lower there), so the einsum asks for HIGHEST precision: a TPU f32 dot
+otherwise rounds its operands to bfloat16, and the STP efficacies would
+lose 16 mantissa bits against the f32 dense path. XLA:CPU computes f32
+dots in f32 either way, so the CPU bits are unchanged.
 """
+import jax
 import jax.numpy as jnp
 
 
@@ -25,4 +32,5 @@ def sparse_window_ref(rows_tk, addr_tk, eff_tk, weights, addresses):
     weights/addresses [R, C] i8 -> [T, C] f32."""
     wg = weights[rows_tk].astype(jnp.float32)              # [T, K, C]
     match = (addresses[rows_tk] == addr_tk[..., None]).astype(jnp.float32)
-    return jnp.einsum("tk,tkc->tc", eff_tk, wg * match)
+    return jnp.einsum("tk,tkc->tc", eff_tk, wg * match,
+                      precision=jax.lax.Precision.HIGHEST)

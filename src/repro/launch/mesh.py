@@ -10,13 +10,8 @@ import jax
 
 
 def _make_mesh(shape, axes):
-    # axis_types landed after this container's jax; Auto is the default
-    # behaviour there anyway, so omit the kwarg when unavailable
-    if hasattr(jax.sharding, "AxisType"):
-        return jax.make_mesh(shape, axes,
-                             axis_types=(jax.sharding.AxisType.Auto,)
-                             * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False):

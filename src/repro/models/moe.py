@@ -114,8 +114,6 @@ def moe_ffn(x, p, arch: ArchConfig, ctx: ShardingCtx, *, positions=None):
 # Optimized expert parallelism (shard_map) — the §Perf hillclimb result
 # ---------------------------------------------------------------------------
 
-_check_kw = None   # shard_map replication-check kwarg, probed on first use
-
 
 def moe_ffn_ep(x, p, arch: ArchConfig, ctx: ShardingCtx, *, positions=None):
     """Expert-parallel MoE with *explicit* per-rank dispatch.
@@ -138,18 +136,6 @@ def moe_ffn_ep(x, p, arch: ArchConfig, ctx: ShardingCtx, *, positions=None):
     tests/test_moe_ep.py on an 8-device mesh).
     """
     from jax.sharding import PartitionSpec as P
-    try:
-        from jax import shard_map
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map
-    global _check_kw
-    if _check_kw is None:
-        # the replication-check kwarg was renamed check_rep -> check_vma
-        # across jax versions; probe once per process
-        import inspect
-        _check_kw = ({"check_vma": False} if "check_vma"
-                     in inspect.signature(shard_map).parameters
-                     else {"check_rep": False})
 
     if ctx.mesh is None:
         return moe_ffn(x, p, arch, ctx, positions=positions)
@@ -223,11 +209,11 @@ def moe_ffn_ep(x, p, arch: ArchConfig, ctx: ShardingCtx, *, positions=None):
 
     xspec = P(data_axes, None, None)
     wspec = P("model", None, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         block, mesh=ctx.mesh,
         in_specs=(xspec, P(None, None), wspec, wspec, wspec),
         out_specs=(xspec, P()),
-        **_check_kw,
+        check_vma=False,
     )(x, p["router"], p["we_gate"], p["we_up"], p["we_down"])
 
     if m.n_shared_experts:

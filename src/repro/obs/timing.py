@@ -120,17 +120,13 @@ def profile_phases(core, state, row_spikes_t, row_addr_t,
 @contextmanager
 def profiler_trace(logdir: Optional[str]):
     """``jax.profiler.trace`` hook: collect a device trace into ``logdir``
-    (viewable in TensorBoard / Perfetto). ``None`` — or an unavailable
-    profiler — makes this a no-op, so callers can thread a knob through
-    unconditionally."""
+    (viewable in TensorBoard / Perfetto). ``None`` makes this a no-op, so
+    callers can thread a knob through unconditionally; a requested trace
+    that the profiler cannot start raises."""
     if logdir is None:
         yield
         return
-    try:
-        jax.profiler.start_trace(logdir)
-    except Exception:                   # profiler backend unavailable
-        yield
-        return
+    jax.profiler.start_trace(logdir)
     try:
         yield
     finally:

@@ -42,23 +42,6 @@ from repro.faults import inject as finject
 from repro.obs import trace as obs_trace
 from repro.wafer.topology import WaferPlan
 
-_check_kw = None   # shard_map replication-check kwarg, probed on first use
-
-
-def _shard_map():
-    try:
-        from jax import shard_map as sm
-    except ImportError:  # older jax
-        from jax.experimental.shard_map import shard_map as sm
-    global _check_kw
-    if _check_kw is None:
-        import inspect
-        _check_kw = ({"check_vma": False} if "check_vma"
-                     in inspect.signature(sm).parameters
-                     else {"check_rep": False})
-    return sm, _check_kw
-
-
 class InterChipRouter:
     """Constant route tables + the per-window routing step.
 
@@ -261,7 +244,6 @@ class InterChipRouter:
 
     # -- shard_map transports ------------------------------------------------
     def _route_sharded(self, out, T, budget, step_budget, routed_in=None):
-        sm, ck = _shard_map()
         axis, dp = self._axis, self._dp
         K_loc = self.K // dp
         L_loc = self.L // dp
@@ -352,8 +334,8 @@ class InterChipRouter:
         n_out = 4 if use_fwd else 3
         in_specs = (self._spec_in,) * (2 if use_fwd else 1)
         out_specs = (self._spec_in,) + (self._spec_rep,) * (n_out - 1)
-        fn = sm(body, mesh=self._mesh, in_specs=in_specs,
-                out_specs=out_specs, **ck)
+        fn = jax.shard_map(body, mesh=self._mesh, in_specs=in_specs,
+                           out_specs=out_specs, check_vma=False)
         res = fn(out, routed_in) if use_fwd else fn(out)
         return res if use_fwd else (*res, None)
 

@@ -227,13 +227,14 @@ def spec(shape, cols=None):
     return ctx.instance_sharding(shape, cols=cols).spec
 
 # divisible fleet + divisible cols: fully mapped
+# (PartitionSpec normalises a one-axis tuple ("data",) to "data")
 assert spec((8, 16, 4), cols=4) == jax.sharding.PartitionSpec(
-    ("data",), None, "model"), spec((8, 16, 4), cols=4)
+    "data", None, "model"), spec((8, 16, 4), cols=4)
 # odd fleet (6 % 4 != 0): instance dim demoted, cols still mapped
 assert spec((6, 16, 4), cols=4)[0] is None
 assert spec((6, 16, 4), cols=4)[2] == "model"
 # odd cols (5 % 2 != 0): column dim demoted, fleet still mapped
-assert spec((8, 16, 5), cols=5)[0] == ("data",)
+assert spec((8, 16, 5), cols=5)[0] == "data"
 assert spec((8, 16, 5), cols=5)[2] is None
 # the demoted sharding must actually be placeable
 x = jax.device_put(jnp.zeros((6, 16, 4)),

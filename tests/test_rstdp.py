@@ -3,10 +3,13 @@
 Claim: "The mean expected reward converges to approximately one for all
 neurons during training ... despite pattern overlap" (40% overlap).
 """
+import jax
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
-from repro.core.hybrid import RSTDPConfig, run_training
+from repro.core.hybrid import (RSTDPConfig, make_experiment,
+                               make_scanned_training, run_training)
 
 
 def _trailing(mr, sel, n=150):
@@ -53,3 +56,20 @@ def test_overlap_zero_also_converges():
     ecfg = RSTDPConfig(overlap=0.0)
     out, state, meta = run_training(n_trials=450, seed=2, ecfg=ecfg)
     assert _trailing(out["mean_reward"], slice(None), n=80) > 0.8
+
+
+def test_scanned_training_twice_leaves_instance_readable():
+    """The scanned program donates its state; a fresh ``init`` must not
+    alias the instance's buffers (``e_leak`` seeds the membranes), or the
+    first run deletes them. Two runs from ``init`` are bit-identical and
+    ``meta["inst"]`` stays readable afterwards."""
+    init, _, meta = make_experiment(prefix=(2,),
+                                    instance_key=jax.random.PRNGKey(0))
+    scanned = make_scanned_training(meta["scanned_training"])
+    stims = jnp.asarray([1, 2, 0, 1], jnp.int32)
+    runs = [jax.device_get(scanned(init(jax.random.PRNGKey(1)), stims))
+            for _ in range(2)]
+    for a, b in zip(jax.tree.leaves(runs[0]), jax.tree.leaves(runs[1])):
+        np.testing.assert_array_equal(a, b)
+    leaves = jax.tree.leaves(meta["inst"])
+    assert leaves and all(np.isfinite(np.asarray(x)).all() for x in leaves)

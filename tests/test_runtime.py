@@ -302,3 +302,36 @@ print("SERVE_SHARDINGS_OK")
                            os.path.abspath(__file__))))
     assert "SERVE_SHARDINGS_OK" in r.stdout, \
         r.stdout[-2000:] + r.stderr[-3000:]
+
+
+@pytest.mark.parametrize("env_dir", [True, False])
+def test_compile_cache_dir_subprocess(env_dir, tmp_path):
+    """``enable_compile_cache`` writes where ``JAX_COMPILATION_CACHE_DIR``
+    says, and only there; without it the cache is the fixed in-checkout
+    ``.jax_cache``. Subprocess: the cache setting is process-global."""
+    code = r"""
+import os, sys
+sys.path.insert(0, "src")
+import jax, jax.numpy as jnp
+from repro.compile_cache import REPO_CACHE, enable_compile_cache
+want = os.environ.get("JAX_COMPILATION_CACHE_DIR") or str(REPO_CACHE)
+assert enable_compile_cache() == want
+assert jax.config.jax_compilation_cache_dir == want
+if os.environ.get("JAX_COMPILATION_CACHE_DIR"):
+    jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+    jax.jit(lambda x: x * 2 + 1)(jnp.arange(3)).block_until_ready()
+    assert os.listdir(want), "nothing cached"
+print("CACHE_OK", REPO_CACHE.name)
+"""
+    env = {k: v for k, v in os.environ.items()
+           if k != "JAX_COMPILATION_CACHE_DIR"}
+    if env_dir:
+        env["JAX_COMPILATION_CACHE_DIR"] = str(tmp_path / "cache")
+    repo = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    before = os.path.exists(os.path.join(repo, ".jax_cache"))
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, cwd=repo, env=env)
+    assert "CACHE_OK .jax_cache" in r.stdout, \
+        r.stdout[-2000:] + r.stderr[-3000:]
+    # only the configured directory is written
+    assert os.path.exists(os.path.join(repo, ".jax_cache")) == before

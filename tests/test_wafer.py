@@ -330,3 +330,55 @@ print("WAFER_SHARDED_OK")
                            os.path.abspath(__file__))))
     assert "WAFER_SHARDED_OK" in r.stdout, \
         r.stdout[-2000:] + r.stderr[-3000:]
+
+
+def test_per_device_wafer_matches_local_subprocess():
+    """A sharded wafer with Pallas kernels (interpret mode here, native on
+    TPU) places one chip's state per device and runs each device's chips
+    under ``shard_map``; the closed loop (spikes, rewards, weights) is
+    bit-identical to the local transport on one device. 4 fake CPU
+    devices (subprocess: device count is fixed at jax init)."""
+    code = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import sys
+sys.path.insert(0, "src")
+import dataclasses
+import numpy as np, jax, jax.numpy as jnp
+from repro.configs.bss2 import BSS2
+from repro.core.hybrid import (RSTDPConfig, make_experiment,
+                               make_scanned_training)
+from repro.launch.mesh import make_smoke_mesh
+from repro.parallel.sharding import ShardingCtx
+
+K = 4
+ecfg = RSTDPConfig(n_inputs=16, n_neurons=32, pattern_size=5,
+                   trial_steps=32)
+cfg = dataclasses.replace(BSS2.reduced(), n_rows=32, n_cols=32)
+ctx = ShardingCtx(mesh=make_smoke_mesh((K, 1)))
+stims = jnp.asarray([1, 2, 0], jnp.int32)
+out = {}
+for name, wctx in (("local", None), ("sharded", ctx)):
+    init, _, meta = make_experiment(
+        cfg=cfg, ecfg=ecfg, instance_key=jax.random.PRNGKey(0), wafer=K,
+        wafer_ctx=wctx, backend="blocked", kernel_impl="interpret",
+        kernel_block=16)
+    st = init(jax.random.PRNGKey(1))
+    if wctx is not None:
+        assert meta["router"]._axis == "data"
+        shards = st.core.syn.weights.addressable_shards
+        assert sorted(s.device.id for s in shards) == [0, 1, 2, 3]
+        assert all(s.data.shape[0] == 1 for s in shards)
+    st, hist = make_scanned_training(meta["scanned_training"])(st, stims)
+    out[name] = jax.device_get((st.core.syn.weights, st.w_signed,
+                                hist["rates"], hist["reward"]))
+for a, b in zip(out["local"], out["sharded"]):
+    np.testing.assert_array_equal(a, b)
+assert out["local"][2].sum() > 0
+print("PER_DEVICE_OK")
+"""
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, cwd=os.path.dirname(os.path.dirname(
+                           os.path.abspath(__file__))))
+    assert "PER_DEVICE_OK" in r.stdout, \
+        r.stdout[-2000:] + r.stderr[-3000:]
