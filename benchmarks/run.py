@@ -48,7 +48,7 @@ def main() -> None:
     from benchmarks import (fig4_calibration, fig8_event_interface,
                             fig11_rstdp, step_time, faults_bench,
                             kernels_bench, mapper_bench, ppuvm_bench,
-                            roofline_table, telemetry_bench, wafer_bench)
+                            roofline_table, wafer_bench)
     suites = [
         ("fig4_calibration", fig4_calibration.run),
         ("fig8_event_interface", fig8_event_interface.run),
@@ -56,7 +56,6 @@ def main() -> None:
         ("step_time", step_time.run),
         ("kernels", kernels_bench.run),
         ("ppuvm", ppuvm_bench.run),
-        ("telemetry", telemetry_bench.run),
         ("wafer", wafer_bench.run),
         ("faults", faults_bench.run),
         ("mapper", mapper_bench.run),

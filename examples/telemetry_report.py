@@ -2,9 +2,11 @@
 
 The commissioning loop the paper's verification methods feed ("From Clean
 Room to Machine Room") starts from exactly this artifact: a short §5
-training with the jit-safe counter pytree enabled, a phase-timing split
-of one emulation window, the specializer-cache stats — merged with config
-and git provenance into JSON + markdown under ``results/``.
+training with the jit-safe counter pytree enabled, its host time, the
+specializer-cache stats — merged with config and git provenance into
+JSON + markdown under ``results/``. Where each layer's device time goes
+is a profiler trace's to say: ``repro.obs.timing.profiler_trace`` with
+the program's layer scopes (docs/telemetry.md).
 
 Run:  PYTHONPATH=src python examples/telemetry_report.py \
           [--trials N] [--json PATH] [--md PATH] [--rule vm|python]
@@ -31,28 +33,20 @@ def main():
     import jax
     from repro.core.hybrid import run_training
     from repro.obs import report as obs_report
-    from repro.obs.timing import CacheDelta, profile_phases
+    from repro.obs.timing import CacheDelta, PhaseTimer
 
-    # --- the run, counters ON, cache delta captured ----------------------
-    with CacheDelta(warn=False) as cd:
+    # --- the run, counters ON, cache delta and host time captured --------
+    timer = PhaseTimer()
+    with CacheDelta(warn=False) as cd, timer.span("run_training"):
         out, state, meta = run_training(n_trials=args.trials, seed=0,
                                         rule_impl=args.rule,
                                         telemetry=True)
     tele = out["telemetry"]
     mr = float(np.median(out["mean_reward"][-1]))
 
-    # --- phase attribution of one emulation window -----------------------
-    core = meta["core"]
-    ecfg = meta["ecfg"]
-    rng = np.random.default_rng(0)
-    ev = (rng.random((ecfg.trial_steps, core.cfg.n_rows)) < 0.02
-          ).astype(np.float32)
-    ad = np.zeros((ecfg.trial_steps, core.cfg.n_rows), np.int8)
-    phases = profile_phases(core, core.init_state(), ev, ad, iters=3)
-
     # --- merge + persist -------------------------------------------------
     rep = obs_report.build_report(
-        "telemetry_demo", telemetry=tele, timings=phases,
+        "telemetry_demo", telemetry=tele, timings=timer.summary(),
         cache=dict(cd.delta),
         config=dict(n_trials=args.trials, rule_impl=args.rule,
                     jax_devices=len(jax.devices())),

@@ -57,6 +57,7 @@ import jax.numpy as jnp
 from repro.configs.bss2 import BSS2Config
 from repro.core import adex, correlation, stp, synapse
 from repro.faults import inject as finject
+from repro.obs import trace as obs_trace
 
 
 class AnnCoreState(NamedTuple):
@@ -235,7 +236,6 @@ class AnnCore:
         bit-identical and off is the same jaxpr (tests/test_obs.py),
         fault injection is backend-invariant (tests/test_faults.py).
         """
-        from repro.obs import trace as obs_trace
         if telemetry is None and self.telemetry:
             telemetry = obs_trace.init_telemetry()
         # dead drivers zero their events before EVERY phase (STP, synaptic
@@ -283,19 +283,20 @@ class AnnCore:
         cross-K round trip (tests/test_mapper.py::TestExactness) runs
         through this entry point via ``repro.wafer.router.run_windows``.
         """
-        from repro.obs import trace as obs_trace
         if telemetry is None and self.telemetry:
             telemetry = obs_trace.init_telemetry()
-        ev, ad = router.merge(routed_ev, row_spikes_t, row_addr_t)
+        with obs_trace.scope("inter_chip_router"):
+            ev, ad = router.merge(routed_ev, row_spikes_t, row_addr_t)
         if router._axis is not None and self._pallas_kernels():
             state, out = self._run_per_device(router, state, ev, ad,
                                               record_v, unroll, telemetry)
         else:
             state, out = self.run(state, ev, ad, record_v=record_v,
                                   unroll=unroll, telemetry=telemetry)
-        routed, tele = router.route(out["spikes"],
-                                    out.get("telemetry", telemetry),
-                                    routed_in=routed_ev)
+        with obs_trace.scope("inter_chip_router"):
+            routed, tele = router.route(out["spikes"],
+                                        out.get("telemetry", telemetry),
+                                        routed_in=routed_ev)
         out["routed"] = routed
         if tele is not None:
             out["telemetry"] = tele
@@ -317,8 +318,10 @@ class AnnCore:
         be automatically partitioned"), so a sharded wafer with Pallas
         kernels needs this per-device program; chips are
         independent within a window, so it computes what ``run`` does.
-        Telemetry counters and fault overlays are fleet-wide and are not
-        split per device here.
+        Telemetry: each device counts its own chips from zero, and
+        ``obs.trace.fold_devices`` folds the devices' counters into the
+        fleet-wide pytree. Fault overlays are fleet-wide and are not split
+        per device here.
 
         The membrane decay factors are computed outside the ``shard_map``.
         On the local path the instance is a constant and XLA folds them on
@@ -326,35 +329,47 @@ class AnnCore:
         and a TPU's exp and division round differently from the host's,
         enough to flip spikes within a few windows."""
         from jax.sharding import PartitionSpec as P
-        if telemetry is not None or self.faults is not None:
+        if self.faults is not None:
             raise NotImplementedError(
-                "a sharded wafer with native kernels runs without "
-                "telemetry and fault overlays")
+                "a sharded wafer with native kernels runs without fault "
+                "overlays")
         axis, K = router._axis, router.K
         chips = lambda tree: jax.tree.map(        # noqa: E731
             lambda x: P(axis) if x.ndim and x.shape[0] == K else P(), tree)
         out_spec = dict(spikes=P(None, axis))
         if record_v:
             out_spec["v"] = P(None, axis)
+        if telemetry is not None:
+            out_spec["telemetry"] = jax.tree.map(lambda _: P(axis),
+                                                 telemetry)
         decays = adex.decay_factors(self.inst["neuron_params"], self.cfg.dt)
 
         def body(inst, dec, st, e, a):
             local = copy.copy(self)
             local.inst = inst
             local._decays = dec
-            return local.run(st, e, a, record_v=record_v, unroll=unroll)
+            local.telemetry = telemetry is not None
+            st, out = local.run(st, e, a, record_v=record_v, unroll=unroll)
+            if telemetry is not None:
+                # a leading device axis, for the fold below
+                out["telemetry"] = jax.tree.map(lambda x: x[None],
+                                                out["telemetry"])
+            return st, out
 
-        return jax.shard_map(
+        state, out = jax.shard_map(
             body, mesh=router._mesh,
             in_specs=(chips(self.inst), chips(decays), chips(state),
                       P(None, axis), P(None, axis)),
             out_specs=(chips(state), out_spec), check_vma=False)(
                 self.inst, decays, state, ev, ad)
+        if telemetry is not None:
+            out["telemetry"] = obs_trace.fold_devices(telemetry,
+                                                      out["telemetry"])
+        return state, out
 
     def _run_oracle(self, state: AnnCoreState, row_spikes_t, row_addr_t,
                     record_v: bool = False, unroll: int = 1,
                     telemetry=None):
-        from repro.obs import trace as obs_trace
 
         def body(s, xs):
             sp, ad = xs
@@ -388,15 +403,18 @@ class AnnCore:
         #    never touches the [.., R, C] synapse array. The calibrated
         #    mismatch scale and the recovery increment are loop-invariant
         #    (bit-exact hoists — same op trees).
-        scale = stp.efficacy_scale(inst["stp_offset"], inst["stp_calib"])
-        recovery = stp.recovery_factor(cfg.stp_tau_rec, dt)
+        with obs_trace.scope("stp"):
+            scale = stp.efficacy_scale(inst["stp_offset"],
+                                       inst["stp_calib"])
+            recovery = stp.recovery_factor(cfg.stp_tau_rec, dt)
 
-        def stp_body(s, sp):
-            eff = stp.efficacy(s, sp, u=cfg.stp_u, scale=scale)
-            return stp.update(s, sp, u=cfg.stp_u, recovery=recovery), eff
+            def stp_body(s, sp):
+                eff = stp.efficacy(s, sp, u=cfg.stp_u, scale=scale)
+                return (stp.update(s, sp, u=cfg.stp_u, recovery=recovery),
+                        eff)
 
-        new_stp, eff_t = jax.lax.scan(stp_body, state.stp, row_spikes_t,
-                                      unroll=unroll)
+            new_stp, eff_t = jax.lax.scan(stp_body, state.stp,
+                                          row_spikes_t, unroll=unroll)
 
         # 2. Dale rows pre-split once per window; synaptic currents for ALL
         #    timesteps in one event x weight matmul (time = batch axis of
@@ -470,25 +488,28 @@ class AnnCore:
         neuron window (phase 3) -> hoisted correlation window (phase 4:
         sensors never feed back into the dynamics within a window, so one
         fused kernel call replays the whole T-window per VMEM tile).
-        ``repro.obs.timing.profile_phases`` times these same phase
-        methods individually."""
-        from repro.obs import trace as obs_trace
+        Each phase runs under its layer's scope
+        (``obs.trace.LAYER_SCOPES``)."""
         cfg = self.cfg
-        new_stp, i_exc_t, i_inh_t, telemetry = self._window_currents(
-            state, row_spikes_t, row_addr_t, unroll, telemetry)
-        new_neuron, rate_counters, recs = self._neuron_window(
-            state.neuron, state.rate_counters, i_exc_t, i_inh_t,
-            record_v, unroll)
+        with obs_trace.scope("synaptic_phase"):
+            new_stp, i_exc_t, i_inh_t, telemetry = self._window_currents(
+                state, row_spikes_t, row_addr_t, unroll, telemetry)
+        with obs_trace.scope("neuron_window"):
+            new_neuron, rate_counters, recs = self._neuron_window(
+                state.neuron, state.rate_counters, i_exc_t, i_inh_t,
+                record_v, unroll)
         out_spikes_t = recs[0]
         if self.faults is not None:
             out_spikes_t = finject.spikes(self.faults, out_spikes_t)
             rate_counters = finject.rates(self.faults, rate_counters,
                                           state.rate_counters,
                                           row_spikes_t.shape[0])
-        new_corr = correlation.window(
-            state.corr, row_spikes_t, out_spikes_t,
-            tau_pre=cfg.neuron.tau_syn_exc, tau_post=cfg.neuron.tau_syn_exc,
-            dt=cfg.dt, impl=self.kernel_impl)
+        with obs_trace.scope("correlation_sensors"):
+            new_corr = correlation.window(
+                state.corr, row_spikes_t, out_spikes_t,
+                tau_pre=cfg.neuron.tau_syn_exc,
+                tau_post=cfg.neuron.tau_syn_exc, dt=cfg.dt,
+                impl=self.kernel_impl)
         new_state = AnnCoreState(neuron=new_neuron, stp=new_stp,
                                  corr=new_corr, syn=state.syn,
                                  rate_counters=rate_counters)

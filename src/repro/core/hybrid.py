@@ -434,21 +434,23 @@ def make_experiment(cfg: BSS2Config = None, ecfg: RSTDPConfig = RSTDPConfig(),
                                     telemetry=state.tele)
         tele = core_out.get("telemetry")
         rates = cs.rate_counters
-        r = _reward(rates, stim)
-        tele = obs_trace.count_trial(tele, rates)
+        with obs_trace.scope("ppu_rule"):
+            r = _reward(rates, stim)
+            tele = obs_trace.count_trial(tele, rates)
 
-        # PPU: R-STDP on the signed PPU weights, using exc-row eligibility
-        if rule_impl == "vm":
-            cs2, rule_state, obs, tele = _vm_signed_update(
-                cs, state, r, k_rule, tele)
-        else:
-            cs2, rule_state, obs = ppu.apply_rule(
-                _signed_rule, cs,
-                dict(mean_reward=state.mean_reward, key=k_rule,
-                     w_signed=state.w_signed),
-                reward=r)
-        tele = obs_trace.count_dw(tele, state.w_signed,
-                                  rule_state["w_signed"])
+            # PPU: R-STDP on the signed PPU weights, using exc-row
+            # eligibility
+            if rule_impl == "vm":
+                cs2, rule_state, obs, tele = _vm_signed_update(
+                    cs, state, r, k_rule, tele)
+            else:
+                cs2, rule_state, obs = ppu.apply_rule(
+                    _signed_rule, cs,
+                    dict(mean_reward=state.mean_reward, key=k_rule,
+                         w_signed=state.w_signed),
+                    reward=r)
+            tele = obs_trace.count_dw(tele, state.w_signed,
+                                      rule_state["w_signed"])
         new = ExperimentState(core=cs2, w_signed=rule_state["w_signed"],
                               mean_reward=rule_state["mean_reward"],
                               key=key_next, tele=tele,
@@ -463,8 +465,9 @@ def make_experiment(cfg: BSS2Config = None, ecfg: RSTDPConfig = RSTDPConfig(),
     def trial(state: ExperimentState, stim) -> Tuple[ExperimentState, Dict]:
         """One fused training trial. stim: int32 in {0,1,2} (the PPU's
         simulated environment picks it upstream or it is scanned over)."""
-        key, k_ev, k_rule = jax.random.split(state.key, 3)
-        ev, addr = _gen_events(k_ev, stim)
+        with obs_trace.scope("event_generation"):
+            key, k_ev, k_rule = jax.random.split(state.key, 3)
+            ev, addr = _gen_events(k_ev, stim)
         return _trial_with(state, stim, ev, addr, k_rule, key)
 
     def scanned_training(state: ExperimentState, stims):
@@ -481,9 +484,10 @@ def make_experiment(cfg: BSS2Config = None, ecfg: RSTDPConfig = RSTDPConfig(),
             k2, k_ev, k_rule = jax.random.split(k, 3)
             return k2, (k2, k_ev, k_rule)
 
-        _, (keys_next, k_evs, k_rules) = jax.lax.scan(
-            key_body, state.key, None, length=n)
-        ev_all, addr_all = jax.vmap(_gen_events)(k_evs, stims)
+        with obs_trace.scope("event_generation"):
+            _, (keys_next, k_evs, k_rules) = jax.lax.scan(
+                key_body, state.key, None, length=n)
+            ev_all, addr_all = jax.vmap(_gen_events)(k_evs, stims)
 
         def body(st, xs):
             stim, ev, addr, k_rule, key_next = xs

@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 
 from repro.core import events
+from repro.obs import trace as obs_trace
 
 WMAX = 63  # 6-bit
 
@@ -73,53 +74,54 @@ SPARSE_MIN_DENSE_WORK = 2 * 1024 * 1024
 def _dense_window(weights, addresses, row_events_t, event_addr_t, gain,
                   impl, const_addr, bb):
     """The dense whole-window path (kernel or broadcasting oracle)."""
-    if impl == "auto":
-        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
-    if impl == "ref":
-        if const_addr:
-            match = (addresses == event_addr_t[0][..., None]
-                     ).astype(jnp.float32)
-            w_eff = weights.astype(jnp.float32) * match
-            if weights.ndim == 2:     # no instance prefix: plain matmul
-                i = row_events_t.astype(jnp.float32) @ w_eff
-            else:
-                i = jnp.einsum("t...r,...rc->t...c",
-                               row_events_t.astype(jnp.float32), w_eff)
-            return i * gain
-        return synaptic_current(weights, addresses, row_events_t,
-                                event_addr_t, gain)
-    from repro.kernels import (fold_instance, fold_instance_time,
-                               unfold_instance_time)
-    from repro.kernels.synray import ops as synray_ops
+    with obs_trace.scope("dense_matmul"):
+        if impl == "auto":
+            impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+        if impl == "ref":
+            if const_addr:
+                match = (addresses == event_addr_t[0][..., None]
+                         ).astype(jnp.float32)
+                w_eff = weights.astype(jnp.float32) * match
+                if weights.ndim == 2:     # no instance prefix: plain matmul
+                    i = row_events_t.astype(jnp.float32) @ w_eff
+                else:
+                    i = jnp.einsum("t...r,...rc->t...c",
+                                   row_events_t.astype(jnp.float32), w_eff)
+                return i * gain
+            return synaptic_current(weights, addresses, row_events_t,
+                                    event_addr_t, gain)
+        from repro.kernels import (fold_instance, fold_instance_time,
+                                   unfold_instance_time)
+        from repro.kernels.synray import ops as synray_ops
 
-    # time is the kernel's batch axis; pad the window up to the batch
-    # block instead of shrinking the block to a divisor of T (the old
-    # ``next(d for d in (8, 4, 2, 1) ...)`` silently degraded to bb=1 for
-    # any odd T). Batch rows are independent, so zero-event pad steps are
-    # exact and sliced off after the call.
-    T = row_events_t.shape[0]
-    if bb is None:
-        bb = min(8, T)
-    pad = -T % bb
-    if pad:
-        row_events_t = jnp.concatenate(
-            [row_events_t,
-             jnp.zeros((pad, *row_events_t.shape[1:]),
-                       row_events_t.dtype)], axis=0)
-        event_addr_t = jnp.concatenate(
-            [event_addr_t,
-             jnp.zeros((pad, *event_addr_t.shape[1:]),
-                       event_addr_t.dtype)], axis=0)
-    prefix = weights.shape[:-2]
-    i = synray_ops.synaptic_current(
-        fold_instance_time(row_events_t.astype(jnp.float32), 1),
-        fold_instance_time(event_addr_t, 1),
-        fold_instance(weights, 2), fold_instance(addresses, 2),
-        impl=impl, bb=bb)
-    i = unfold_instance_time(i, prefix)
-    if pad:
-        i = i[:T]
-    return i * gain
+        # time is the kernel's batch axis; pad the window up to the batch
+        # block instead of shrinking the block to a divisor of T (the old
+        # ``next(d for d in (8, 4, 2, 1) ...)`` silently degraded to bb=1 for
+        # any odd T). Batch rows are independent, so zero-event pad steps are
+        # exact and sliced off after the call.
+        T = row_events_t.shape[0]
+        if bb is None:
+            bb = min(8, T)
+        pad = -T % bb
+        if pad:
+            row_events_t = jnp.concatenate(
+                [row_events_t,
+                 jnp.zeros((pad, *row_events_t.shape[1:]),
+                           row_events_t.dtype)], axis=0)
+            event_addr_t = jnp.concatenate(
+                [event_addr_t,
+                 jnp.zeros((pad, *event_addr_t.shape[1:]),
+                           event_addr_t.dtype)], axis=0)
+        prefix = weights.shape[:-2]
+        i = synray_ops.synaptic_current(
+            fold_instance_time(row_events_t.astype(jnp.float32), 1),
+            fold_instance_time(event_addr_t, 1),
+            fold_instance(weights, 2), fold_instance(addresses, 2),
+            impl=impl, bb=bb)
+        i = unfold_instance_time(i, prefix)
+        if pad:
+            i = i[:T]
+        return i * gain
 
 
 def _sparse_window(weights, addresses, row_events_t, event_addr_t, gain,
@@ -212,7 +214,6 @@ def synaptic_current_window(weights, addresses, row_events_t, event_addr_t,
     counters only read the census the gate already computes), so on/off
     stays bit-identical.
     """
-    from repro.obs import trace as obs_trace
     if impl == "dense":
         impl, sparse = "auto", "never"
     elif impl == "sparse":
@@ -249,8 +250,9 @@ def synaptic_current_window(weights, addresses, row_events_t, event_addr_t,
             return i
         return i, obs_trace.count_route(telemetry, sparse=True)
 
-    n, kmax = events.window_stats(row_events_t)
-    fits = events.census_fits(n, kmax, max_events, k_cap)
+    with obs_trace.scope("census"):
+        n, kmax = events.window_stats(row_events_t)
+        fits = events.census_fits(n, kmax, max_events, k_cap)
     i = jax.lax.cond(
         fits,
         lambda: _sparse_window(weights, addresses, row_events_t,

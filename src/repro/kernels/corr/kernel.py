@@ -86,6 +86,7 @@ def correlation_window_pallas(pre, post, tp0, tq0, ac0, aa0, *,
     col_spec = pl.BlockSpec((1, 1, cb), lambda n, i, j: (n, 0, j))
     out = pl.pallas_call(
         functools.partial(_kernel, lam=lam, sat=sat),
+        name="corr",
         grid=grid,
         in_specs=[
             pl.BlockSpec((1, T, rb), lambda n, i, j: (n, 0, i)),

@@ -121,6 +121,7 @@ def neuron_window_pallas(ie_t, ii_t, state6, params12, *, dt: float,
     out = pl.pallas_call(
         functools.partial(_kernel, dt=dt, use_adex=use_adex, T=T, blk=blk,
                           record_v=record_v),
+        name="neuron_scan",
         grid=grid,
         in_specs=[drive_spec, drive_spec, state_spec, par_spec],
         out_specs=out_specs,

@@ -65,6 +65,7 @@ def rstdp_update_pallas(weights, a_causal, a_acausal, cadc_offset, cadc_gain,
     out = pl.pallas_call(
         functools.partial(_kernel, eta=eta, cadc_scale=cadc_scale,
                           wmax=wmax, cadc_max=cadc_max),
+        name="ppu_update",
         grid=grid,
         in_specs=[row_spec, row_spec, row_spec, col_spec, col_spec, col_spec,
                   row_spec],

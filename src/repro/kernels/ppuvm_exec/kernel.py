@@ -87,6 +87,7 @@ def run_program_pallas(words, weights, qc, qa, rates_fx, mod, noise, *,
     )
     out = pl.pallas_call(
         functools.partial(_kernel, n_words=int(words.shape[0])),
+        name="ppuvm_exec",
         grid_spec=grid_spec,
         out_shape=[jax.ShapeDtypeStruct((R, C), jnp.int32),
                    jax.ShapeDtypeStruct((isa.N_REGS, R, C), jnp.int32)],

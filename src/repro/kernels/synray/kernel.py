@@ -78,6 +78,7 @@ def synaptic_current_pallas(events, event_addr, weights, addresses, *,
     w_spec = pl.BlockSpec((1, rb, cb), lambda n, i, j, k: (n, k, j))
     out = pl.pallas_call(
         _kernel,
+        name="synray",
         grid=grid,
         in_specs=[ev_spec, ev_spec, w_spec, w_spec],
         out_specs=pl.BlockSpec((1, bb, cb), lambda n, i, j, k: (n, i, j)),

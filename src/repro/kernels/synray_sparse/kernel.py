@@ -72,6 +72,7 @@ def sparse_window_pallas(rows_tk, addr_tk, eff_tk, weights, addresses, *,
     w_spec = pl.BlockSpec((1, R, cb), lambda n, j: (n, 0, j))
     out = pl.pallas_call(
         _kernel,
+        name="synray_sparse",
         grid=grid,
         in_specs=[rec_spec, rec_spec, rec_spec, w_spec, w_spec],
         out_specs=pl.BlockSpec((1, T, cb), lambda n, j: (n, 0, j)),

@@ -29,6 +29,7 @@ import jax
 from repro.core import events as ev_mod
 from repro.kernels.synray_sparse.kernel import sparse_window_pallas
 from repro.kernels.synray_sparse.ref import sparse_window_ref
+from repro.obs import trace as obs_trace
 
 # jitted once at import — same rationale as the synray wrapper
 _ref_jit = jax.jit(sparse_window_ref)
@@ -74,7 +75,9 @@ def synaptic_current_sparse(row_events_t, event_addr_t, weights, addresses,
     event_addr_t [N, T, R] int; weights/addresses [N, R, C] i8
     -> [N, T, C] f32. Drops events beyond the static capacities — see
     module docstring."""
-    rows_tk, addr_tk, eff_tk = _pack_regroup(
-        row_events_t, event_addr_t, max_events=max_events, k_cap=k_cap)
-    return sparse_window(rows_tk, addr_tk, eff_tk, weights, addresses,
-                         impl=impl, **block_kw)
+    with obs_trace.scope("pack_events"):
+        rows_tk, addr_tk, eff_tk = _pack_regroup(
+            row_events_t, event_addr_t, max_events=max_events, k_cap=k_cap)
+    with obs_trace.scope("gather_matmul"):
+        return sparse_window(rows_tk, addr_tk, eff_tk, weights, addresses,
+                             impl=impl, **block_kw)

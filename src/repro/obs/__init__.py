@@ -11,18 +11,18 @@ run a structured report.
 Three layers:
 
 ``repro.obs.trace``
-    A jit-safe ``Telemetry`` pytree of counters carried through the
-    training scan. ``None`` means OFF and compiles to *nothing*: every
+    The layer scopes (``LAYER_SCOPES``, ``scope``) that name each layer's
+    ops in the compiled program, and a jit-safe ``Telemetry`` pytree of
+    counters carried through the training scan. ``None`` means OFF and compiles to *nothing*: every
     update helper is the identity on ``None``, so the telemetry-off
     program graph is byte-identical to the pre-telemetry one, and
     telemetry on/off is bit-identical in spikes/weights (the counters
     only read the existing dataflow).
 
 ``repro.obs.timing``
-    Host-side phase profiling: ``block_until_ready``-bracketed spans
-    (``PhaseTimer``), per-phase AnnCore profiling (``profile_phases``),
-    ``jax.profiler`` trace hooks, and specializer-cache snapshots with
-    eviction-storm detection.
+    Host-side timing: ``block_until_ready``-bracketed spans
+    (``PhaseTimer``), the ``jax.profiler`` trace hook, and
+    specializer-cache snapshots with eviction-storm detection.
 
 ``repro.obs.report``
     Structured run reports (JSON + markdown) merging counters, timings,

@@ -3,7 +3,7 @@
 Everything here is HOST-side instrumentation: jitted programs cannot be
 timed from inside, so phases are measured by bracketing dispatches with
 ``jax.block_until_ready`` (async dispatch otherwise attributes a phase's
-cost to whoever synchronizes first). Three tools:
+cost to whoever synchronizes first). Two tools:
 
 ``PhaseTimer``
     Accumulating named spans. ``with timer.span("synray") as mark:``
@@ -11,19 +11,12 @@ cost to whoever synchronizes first). Three tools:
     blocks on them before reading the clock. ``summary()`` gives
     count/total/mean/best per phase.
 
-``profile_phases``
-    Times the AnnCore window phase-by-phase — the STP + synray current
-    phase, the neuron integration, and the hoisted correlation window —
-    by jitting each phase function separately (the same op trees the
-    fused program runs; per-phase dispatch adds overhead, so the split
-    is attribution, not an end-to-end time — ``total`` times the real
-    fused ``run`` for that).
-
 ``profiler_trace`` / ``cache_snapshot`` / ``CacheDelta``
-    ``jax.profiler`` trace hook (no-op when unavailable), and
-    specializer-cache snapshots with eviction-storm detection: more
-    misses than the LRU capacity within one delta means the working set
-    thrashes the cache and every upload recompiles.
+    ``jax.profiler`` trace hook (``None`` is a no-op; the program's layer
+    scopes, ``repro.obs.trace.LAYER_SCOPES``, name each device op in the
+    trace), and specializer-cache snapshots with eviction-storm
+    detection: more misses than the LRU capacity within one delta means
+    the working set thrashes the cache and every upload recompiles.
 """
 from __future__ import annotations
 
@@ -73,48 +66,6 @@ class PhaseTimer:
                              mean_us=sum(ts) / len(ts) * 1e6,
                              best_us=min(ts) * 1e6)
         return out
-
-
-def profile_phases(core, state, row_spikes_t, row_addr_t,
-                   iters: int = 5, timer: Optional[PhaseTimer] = None
-                   ) -> Dict[str, Dict[str, float]]:
-    """Per-phase timings of one AnnCore window on ``core``'s backend.
-
-    Phases (the fused/blocked pipeline of ``AnnCore._run_windowed``):
-      ``synray``  STP efficacy scan + whole-window synaptic currents
-      ``neuron``  membrane integration (per-dt scan or time-blocked)
-      ``corr``    hoisted correlation-sensor window
-      ``total``   the actual fused ``core.run`` dispatch (ground truth —
-                  the phase split re-dispatches per phase)
-    """
-    timer = timer or PhaseTimer()
-    unroll = 4
-
-    win = jax.jit(lambda s, ev, ad: core._window_currents(
-        s, ev, ad, unroll)[:3])
-    _, i_exc_t, i_inh_t = timer.time_fn(
-        "synray", win, state, row_spikes_t, row_addr_t, iters=iters)
-
-    neuron = jax.jit(lambda n, rc, ie, ii: core._neuron_window(
-        n, rc, ie, ii, record_v=False, unroll=unroll))
-    timer.time_fn("neuron", neuron, state.neuron, state.rate_counters,
-                  i_exc_t, i_inh_t, iters=iters)
-
-    from repro.core import correlation
-    cfg = core.cfg
-    corr = jax.jit(lambda c, ev, sp: correlation.window(
-        c, ev, sp, tau_pre=cfg.neuron.tau_syn_exc,
-        tau_post=cfg.neuron.tau_syn_exc, dt=cfg.dt,
-        impl=core.kernel_impl))
-    zero_sp = jax.numpy.zeros(
-        (*row_spikes_t.shape[:-1], cfg.n_cols), jax.numpy.float32)
-    timer.time_fn("corr", corr, state.corr, row_spikes_t, zero_sp,
-                  iters=iters)
-
-    total = jax.jit(core.run)
-    timer.time_fn("total", total, state, row_spikes_t, row_addr_t,
-                  iters=iters)
-    return timer.summary()
 
 
 @contextmanager

@@ -1,4 +1,8 @@
-"""Jit-safe telemetry counters for the emulation stack.
+"""Layer scopes and jit-safe telemetry counters for the emulation stack.
+
+``LAYER_SCOPES`` declares the ``jax.named_scope`` each layer of the
+emulator runs under, and ``scope`` enters one; they name ops in a
+profile and add no instruction (see their comment below).
 
 ``Telemetry`` is a pytree of scalar counters (plus one fixed-size
 histogram) threaded through the training scan as part of the carry. The
@@ -70,8 +74,46 @@ from __future__ import annotations
 
 from typing import NamedTuple, Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
+
+# ---------------------------------------------------------------------------
+# Layer scopes
+# ---------------------------------------------------------------------------
+
+# The emulator's layers, as the names of the ``jax.named_scope``s that wrap
+# them (scope name -> the scope it nests in, None at the top). A scope only
+# writes the ``op_name`` metadata of the ops traced under it: a profile
+# (``repro.obs.timing.profiler_trace``, viewed in TensorBoard or Perfetto)
+# shows each op under its layer, and the compiled instructions are the same
+# with the scopes as without them. The children of ``synaptic_phase`` split
+# its work: the STP efficacy scan, the density gate's event census, and the
+# sparse route (event packing, gather-matmul) or the dense route; inside the
+# gate's ``lax.cond`` each route keeps its own child scope.
+LAYER_SCOPES = {
+    "synaptic_phase": None,
+    "stp": "synaptic_phase",
+    "census": "synaptic_phase",
+    "pack_events": "synaptic_phase",
+    "gather_matmul": "synaptic_phase",
+    "dense_matmul": "synaptic_phase",
+    "neuron_window": None,
+    "correlation_sensors": None,
+    "ppu_rule": None,
+    "event_generation": None,
+    "inter_chip_router": None,
+}
+
+
+def scope(name: str):
+    """``jax.named_scope(name)`` for a declared layer scope; an undeclared
+    name raises, so a profile never shows a layer the table lacks."""
+    if name not in LAYER_SCOPES:
+        raise ValueError(f"undeclared layer scope {name!r}; declared: "
+                         f"{sorted(LAYER_SCOPES)}")
+    return jax.named_scope(name)
+
 
 # |dw| histogram bin edges in weight LSBs: bin 0 is "below one Q8.8 LSB"
 # (effectively unchanged), the rest are log2-spaced up to the ±45 clip
@@ -234,11 +276,19 @@ def count_dw(tele: Optional[Telemetry], w_old, w_new
         return None
     dw = jnp.abs(jnp.asarray(w_new, jnp.float32)
                  - jnp.asarray(w_old, jnp.float32)).reshape(-1)
-    idx = jnp.searchsorted(jnp.asarray(DW_EDGES), dw)
+    # bin b holds the |dw| with exactly b edges below them (the bins of
+    # ``searchsorted``), counted as differences of "above edge k" counts:
+    # fused reductions, where a scatter-add of every synapse into the bins
+    # serializes on a TPU (on a v5e the scatter cost about 590 us per
+    # emulated chip-trial, all the counters together 4-15 us without it)
+    above = jnp.sum(dw[None, :] > jnp.asarray(DW_EDGES)[:, None], axis=1,
+                    dtype=jnp.int32)
+    ge = jnp.concatenate([jnp.full((1,), dw.size, jnp.int32), above,
+                          jnp.zeros((1,), jnp.int32)])
     return tele._replace(
         dw_updates=tele.dw_updates + 1,
         dw_abs_max=jnp.maximum(tele.dw_abs_max, jnp.max(dw)),
-        dw_hist=tele.dw_hist.at[idx].add(1))
+        dw_hist=tele.dw_hist + ge[:-1] - ge[1:])
 
 
 def count_faults(tele: Optional[Telemetry], faults) -> Optional[Telemetry]:
@@ -280,6 +330,39 @@ def count_reroutes(tele: Optional[Telemetry], n_fwd) -> Optional[Telemetry]:
         return tele
     return tele._replace(
         link_reroutes=tele.link_reroutes + n_fwd.astype(jnp.int32))
+
+
+# Counters that hold a maximum or a gauge, and counters of windows (every
+# device of a sharded wafer runs every window); the rest are totals over
+# the chips.
+_MAX_FIELDS = ("census_events_max", "census_k_max", "link_events_max",
+               "faults_injected", "faults_detected", "blacklisted_rows",
+               "dw_abs_max")
+_WINDOW_FIELDS = ("steps", "dense_windows", "sparse_windows",
+                  "gated_windows", "overflow_fallbacks")
+
+
+def fold_devices(tele: Optional[Telemetry], parts: Telemetry
+                 ) -> Optional[Telemetry]:
+    """Fold the counters each device of a sharded window counted from zero
+    (``parts``: every leaf with a leading device axis) into the fleet-wide
+    ``tele``: totals over the chips add up, maxima and gauges take the
+    largest, and counts of windows and steps take the largest device's
+    count. Each device gates its own chips' census, so the route counts
+    equal those of the unsharded program whenever the devices' gates
+    agree."""
+    if tele is None:
+        return None
+    out = {}
+    for f, old in tele._asdict().items():
+        new = getattr(parts, f)
+        if f in _MAX_FIELDS:
+            out[f] = jnp.maximum(old, jnp.max(new, axis=0))
+        elif f in _WINDOW_FIELDS:
+            out[f] = old + jnp.max(new, axis=0)
+        else:
+            out[f] = old + jnp.sum(new, axis=0)
+    return Telemetry(**out)
 
 
 # ---------------------------------------------------------------------------

@@ -12,13 +12,18 @@ Three invariants pin down ``repro.obs``:
   * **zero retrace** — emitting (or re-emitting) the host summary/report
     never retraces the compiled training program.
 
-Plus the first-divergence locator (``repro.verif.mismatch``), the phase
-timer, the run report, and the specializer-cache eviction accounting.
+Plus the layer scopes (each declared scope names ops of the compiled
+program, and the scopes add no instruction), the first-divergence
+locator (``repro.verif.mismatch``), the phase timer, the run report, and
+the specializer-cache eviction accounting.
 
 ``ANNCORE_KERNEL_IMPL`` (default "auto") forces the kernel impl — the
 tier-2 CI observability job runs this suite under "interpret".
 """
+import contextlib
+import dataclasses
 import os
+import re
 import warnings
 
 import jax
@@ -29,7 +34,8 @@ import pytest
 from repro.configs.bss2 import BSS2
 from repro.core import synapse
 from repro.core.anncore import AnnCore
-from repro.core.hybrid import make_scanned_training, run_training
+from repro.core.hybrid import (RSTDPConfig, make_experiment,
+                               make_scanned_training, run_training)
 from repro.obs import report as obs_report
 from repro.obs import timing as obs_timing
 from repro.obs import trace as obs_trace
@@ -195,6 +201,21 @@ def test_dw_histogram_hand_count():
     assert s["dw_abs_max"] == pytest.approx(40.0)
 
 
+def test_dw_histogram_matches_searchsorted_bins():
+    """|dw| on the edges fall in the bin below them, as with
+    ``searchsorted``; random |dw| of three magnitudes bin as it does."""
+    rng = np.random.default_rng(0)
+    for scale in (0.01, 1.0, 50.0):
+        w = rng.normal(0.0, scale, (3, 16, 16)).astype(np.float32)
+        w[0, 0, :len(obs_trace.DW_EDGES)] = obs_trace.DW_EDGES
+        tele = obs_trace.count_dw(obs_trace.init_telemetry(),
+                                  np.zeros_like(w), w)
+        expect = np.bincount(np.searchsorted(obs_trace.DW_EDGES,
+                                             np.abs(w).ravel()),
+                             minlength=obs_trace.DW_BINS)
+        np.testing.assert_array_equal(np.asarray(tele.dw_hist), expect)
+
+
 def test_update_helpers_identity_on_none():
     assert obs_trace.count_run(None, jnp.zeros((4, 4)),
                                jnp.zeros((4, 4))) is None
@@ -243,16 +264,6 @@ def test_phase_timer_spans():
     s = t.summary()
     assert s["a"]["count"] == 1 and s["b"]["count"] == 2
     assert s["b"]["best_us"] <= s["b"]["mean_us"] + 1e-9
-
-
-def test_profile_phases_keys():
-    cfg = BSS2.reduced()
-    core = AnnCore(cfg, ideal_instance(cfg), kernel_impl=KERNEL_IMPL)
-    ev, ad = _events(32, cfg.n_rows, p=0.05)
-    s = obs_timing.profile_phases(core, core.init_state(), ev,
-                                  np.asarray(ad), iters=1)
-    assert set(s) >= {"synray", "neuron", "corr", "total"}
-    assert all(v["best_us"] > 0 for v in s.values())
 
 
 def test_profiler_trace_noop():
@@ -426,3 +437,102 @@ def test_instance_prefix_counters():
     s = obs_trace.summary(out["telemetry"])
     assert s["in_events"] == 2 * int(np.count_nonzero(np.asarray(ev)))
     assert s["out_spikes"] == int(np.asarray(out["spikes"]).sum())
+
+
+# ---------------------------------------------------------------------------
+# Layer scopes: every declared scope names ops of the compiled program, and
+# the scopes write metadata only
+# ---------------------------------------------------------------------------
+
+def _scanned_text(wafer=None, scopes=True):
+    """The compiled text of a tiny §5 experiment's scanned training. The
+    density gate's work floor is lifted, so the program holds the census
+    and both routes of the gate's ``lax.cond`` (the sparse route:
+    packing, gather-matmul; the dense route: the dense matmul)."""
+    ecfg = RSTDPConfig(n_inputs=16, n_neurons=16, pattern_size=5,
+                       trial_steps=32)
+    cfg = dataclasses.replace(BSS2.reduced(), n_rows=32, n_cols=16)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(synapse, "SPARSE_MIN_DENSE_WORK", 0)
+        if not scopes:
+            mp.setattr(obs_trace, "scope",
+                       lambda name: contextlib.nullcontext())
+        init, _, meta = make_experiment(
+            cfg=cfg, ecfg=ecfg, instance_key=jax.random.PRNGKey(0),
+            wafer=wafer)
+        state = jax.eval_shape(init, jax.random.PRNGKey(1))
+        stims = jax.ShapeDtypeStruct((3,), jnp.int32)
+        return make_scanned_training(meta["scanned_training"]).lower(
+            state, stims).compile().as_text()
+
+
+def _without_metadata(hlo_text):
+    """A compiled program's text without its metadata: each instruction's
+    ``metadata={...}`` and the tables of source files and stack frames."""
+    text = re.sub(r",?\s*metadata=\{[^}]*\}", "", hlo_text)
+    out, table = [], False
+    for line in text.splitlines():
+        if line in ("FileNames", "FunctionNames", "FileLocations",
+                    "StackFrames"):
+            table = True
+        elif table and (not line.strip()
+                        or line.startswith(("%", "ENTRY", "HloModule"))):
+            table = False
+        if not table:
+            out.append(line)
+    return "\n".join(out)
+
+
+@pytest.fixture(scope="module")
+def scoped_texts():
+    return dict(chip=_scanned_text(), wafer=_scanned_text(wafer=2))
+
+
+def test_scopes_add_no_instruction(scoped_texts):
+    """With the scopes as without them (``scope`` patched to a null
+    context), the compiled programs differ only in their metadata."""
+    plain = _scanned_text(scopes=False)
+    assert plain != scoped_texts["chip"]
+    assert _without_metadata(plain) == _without_metadata(
+        scoped_texts["chip"])
+
+
+@pytest.mark.parametrize("name", sorted(obs_trace.LAYER_SCOPES))
+def test_layer_scope_names_compiled_ops(scoped_texts, name):
+    """Each declared scope is a component of some op's ``op_name``: the
+    router's in the tiny wafer, every other in the single chip."""
+    text = scoped_texts["wafer" if name == "inter_chip_router" else "chip"]
+    paths = re.findall(r'op_name="([^"]*)"', text)
+    hits = [p for p in paths if name in p.split("/")]
+    assert hits, name
+    parent = obs_trace.LAYER_SCOPES[name]
+    if parent is not None:
+        assert all(parent in p.split("/")[:p.split("/").index(name)]
+                   for p in hits), (name, hits[:3])
+
+
+def test_scope_rejects_undeclared_name():
+    with pytest.raises(ValueError, match="undeclared"):
+        obs_trace.scope("synapse")
+
+
+def test_fold_devices_sums_maxima_and_windows():
+    """Per-device counters of one sharded window fold into the fleet's:
+    per-chip totals add up, maxima take the largest, and window counts
+    (each device runs every window) take the largest device's count."""
+    tele = obs_trace.init_telemetry()._replace(
+        in_events=jnp.int32(5), steps=jnp.int32(32),
+        census_events_max=jnp.int32(9))
+    zero = obs_trace.init_telemetry()
+    parts = jax.tree.map(lambda *xs: jnp.stack(xs), zero._replace(
+        in_events=jnp.int32(3), steps=jnp.int32(32),
+        sparse_windows=jnp.int32(2), gated_windows=jnp.int32(2),
+        census_events_max=jnp.int32(4)), zero._replace(
+        in_events=jnp.int32(4), steps=jnp.int32(32),
+        sparse_windows=jnp.int32(2), gated_windows=jnp.int32(2),
+        census_events_max=jnp.int32(11)))
+    s = obs_trace.summary(obs_trace.fold_devices(tele, parts))
+    assert (s["in_events"], s["steps"], s["sparse_windows"],
+            s["gated_windows"], s["census_events_max"]) == (12, 64, 2, 2,
+                                                            11)
+    assert obs_trace.fold_devices(None, parts) is None

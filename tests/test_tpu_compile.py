@@ -117,3 +117,43 @@ def test_full_size_blocked_trial_compiles_for_v5e(one_chip):
     text = _compile_text(trial, (state, stim), one_chip)
     # two synray halves, the neuron window and the correlation window
     assert text.count("tpu_custom_call") >= 4, text.count("tpu_custom_call")
+
+
+def test_full_size_trial_scopes_add_no_instruction_for_v5e(one_chip):
+    """The layer scopes write metadata only: the full-size trial compiled
+    for a v5e with the scopes and with ``scope`` patched to a null
+    context differ in nothing but their metadata, and each kernel's
+    custom call is named after its kernel."""
+    import contextlib
+    import re
+
+    from repro.core.hybrid import RSTDPConfig, make_experiment
+    from repro.obs import trace as obs_trace
+
+    R, C = SIZES["full"]
+    cfg = dataclasses.replace(BSS2, n_rows=R, n_cols=C, n_neurons=C)
+    ecfg = RSTDPConfig(n_inputs=R // 2, n_neurons=C, pattern_size=5,
+                       trial_steps=T)
+
+    def text():
+        init, trial, _ = make_experiment(cfg=cfg, ecfg=ecfg, prefix=(2,),
+                                         backend="blocked",
+                                         kernel_impl="pallas")
+        state = jax.eval_shape(init, jax.random.PRNGKey(0))
+        stim = jax.ShapeDtypeStruct((), jnp.int32)
+        return _compile_text(trial, (state, stim), one_chip)
+
+    def bare(t):
+        t = re.sub(r",?\s*metadata=\{[^}]*\}", "", t)
+        return re.sub(r"\n(FileNames|FunctionNames|FileLocations|"
+                      r"StackFrames)\n(?:\d+ .*\n)*", "\n", t)
+
+    scoped = text()
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(obs_trace, "scope", lambda name: contextlib.nullcontext())
+        plain = text()
+    assert 'synaptic_phase/' in scoped and 'synaptic_phase/' not in plain
+    assert bare(scoped) == bare(plain)
+    calls = re.findall(r"%(\w+)\.\d+ = .*? custom-call\(.*"
+                       r'custom_call_target="tpu_custom_call"', scoped)
+    assert {"synray", "neuron_scan", "corr"} <= set(calls), calls

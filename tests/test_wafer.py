@@ -382,3 +382,55 @@ print("PER_DEVICE_OK")
                            os.path.abspath(__file__))))
     assert "PER_DEVICE_OK" in r.stdout, \
         r.stdout[-2000:] + r.stderr[-3000:]
+
+
+def test_per_device_wafer_telemetry_matches_local_subprocess():
+    """The per-device wafer counts telemetry: each device counts its own
+    chips under ``shard_map`` and the counts fold into the fleet-wide
+    pytree, equal to the local transport's counters, with the closed
+    loop still bit-identical. 4 fake CPU devices, kernels interpreted."""
+    code = r"""
+import os
+os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+import sys
+sys.path.insert(0, "src")
+import dataclasses
+import numpy as np, jax, jax.numpy as jnp
+from repro.configs.bss2 import BSS2
+from repro.core.hybrid import (RSTDPConfig, make_experiment,
+                               make_scanned_training)
+from repro.launch.mesh import make_smoke_mesh
+from repro.obs import trace as obs_trace
+from repro.parallel.sharding import ShardingCtx
+
+K = 4
+ecfg = RSTDPConfig(n_inputs=16, n_neurons=32, pattern_size=5,
+                   trial_steps=32)
+cfg = dataclasses.replace(BSS2.reduced(), n_rows=32, n_cols=32)
+ctx = ShardingCtx(mesh=make_smoke_mesh((K, 1)))
+stims = jnp.asarray([1, 2, 0], jnp.int32)
+out, tele = {}, {}
+for name, wctx in (("local", None), ("sharded", ctx)):
+    init, _, meta = make_experiment(
+        cfg=cfg, ecfg=ecfg, instance_key=jax.random.PRNGKey(0), wafer=K,
+        wafer_ctx=wctx, backend="blocked", kernel_impl="interpret",
+        kernel_block=16, telemetry=True)
+    st = init(jax.random.PRNGKey(1))
+    st, hist = make_scanned_training(meta["scanned_training"])(st, stims)
+    out[name] = jax.device_get((st.core.syn.weights, st.w_signed,
+                                hist["rates"], hist["reward"]))
+    tele[name] = obs_trace.summary(st.tele)
+for a, b in zip(out["local"], out["sharded"]):
+    np.testing.assert_array_equal(a, b)
+assert tele["local"] == tele["sharded"], (tele["local"], tele["sharded"])
+t = tele["local"]
+assert t["trials"] == 3 and t["steps"] == 3 * 32, t
+assert t["out_spikes"] == int(out["local"][2].sum()) > 0, t
+assert t["routed_events"] > 0, t
+print("PER_DEVICE_TELEMETRY_OK")
+"""
+    r = subprocess.run([sys.executable, "-c", code], capture_output=True,
+                       text=True, cwd=os.path.dirname(os.path.dirname(
+                           os.path.abspath(__file__))))
+    assert "PER_DEVICE_TELEMETRY_OK" in r.stdout, \
+        r.stdout[-2000:] + r.stderr[-3000:]
