@@ -5,10 +5,18 @@ and the silicon verification budgets the event bus at ~0.4M events/s
 (fig8 reproduces ~0.4M events/s on the software path). The dense
 emulation nevertheless pays the full [T, R] x [R, C] matmul per window
 even when almost no rows fired. This module is the packing layer of the
-sparse backend (``repro.kernels.synray_sparse``): a window's [T, R] row
-events + per-row event addresses become a compact fixed-capacity stream
-of ``(t, row, addr, efficacy)`` records — the software analogue of the
-packed event frames SpikeHard's ``dma_controller.v`` streams.
+event-sparse paths: a window's [T, R] row events + per-row event
+addresses become a compact fixed-capacity stream of ``(t, row, addr,
+efficacy)`` records — the software analogue of the packed event frames
+SpikeHard's ``dma_controller.v`` streams.
+
+The sparse synaptic route (``repro.kernels.synray_sparse``) consumes the
+records regrouped per step, a [T, K] grid. ``pack_regrouped`` builds that
+grid straight from the window, by per-step ordinals and one-hot
+reductions, with no scatter; it gives exactly the records of
+``pack_events`` followed by ``regroup_events``. The stream itself remains
+the inter-chip router's per-link transport (``pack_events_batch``) and
+the reference the tests hold ``pack_regrouped`` to.
 
 Everything here jits: the capacity ``max_events`` is static and a
 validity mask marks the live records. Records are t-major (sorted by
@@ -211,6 +219,46 @@ def regroup_events(stream: EventStream, T: int, k_cap: int
     eff_tk = jnp.zeros((T * k_cap,), jnp.float32).at[dst].set(
         stream.eff, mode="drop").reshape(T, k_cap)
     return rows_tk, addr_tk, eff_tk
+
+
+def pack_regrouped(row_events_t, event_addr_t, max_events: int, k_cap: int
+                   ) -> Tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """[.., T, R] events -> the [.., T, K] record grid, with no stream.
+
+    Bit-identical to ``regroup_events(pack_events(ev, ad, max_events), T,
+    k_cap)`` on every input, overflow included: a fired row takes slot
+    ``k``, its ordinal within the step, when the stream would have stored
+    it (t-major index below ``max_events``) and regrouping would have kept
+    it (``k < k_cap``). Each slot is then a sum over R of a one-hot
+    select: at most one term is nonzero, so the sum is exact. No scatter:
+    a TPU serializes scatter updates, and the stream form pays that on
+    every one of the T * R slots. Any instance prefix.
+    """
+    T, R = row_events_t.shape[-2:]
+    eff = row_events_t.astype(jnp.float32)
+    fired = eff != 0.0
+    # ordinals as 0/1 matmuls with f32 sums: exact on every backend, and
+    # MXU work on a TPU in place of cumsum reduce-windows
+    bits = fired.astype(jnp.bfloat16)
+    ordinal = jnp.matmul(bits, jnp.triu(jnp.ones((R, R), jnp.bfloat16)),
+                         preferred_element_type=jnp.float32) - 1.0
+    first = jnp.sum(jnp.matmul(jnp.tril(jnp.ones((T, T), jnp.bfloat16), -1),
+                               bits, preferred_element_type=jnp.float32),
+                    axis=-1, keepdims=True)      # stream index of slot 0
+    keep = fired & (first + ordinal < max_events) & (ordinal < k_cap)
+    slot = jnp.where(keep, ordinal.astype(jnp.int32), k_cap)
+    # [.., K, R, T]: the sum over R runs across sublanes with T on the
+    # lanes, five times faster on a TPU v5e than R on the lanes
+    hit = (jnp.swapaxes(slot, -1, -2)[..., None, :, :]
+           == jnp.arange(k_cap, dtype=jnp.int32)[:, None, None])
+
+    def gather(values_rt):
+        picked = jnp.where(hit, values_rt[..., None, :, :], 0)
+        return jnp.swapaxes(jnp.sum(picked, axis=-2), -1, -2)
+
+    rows = jnp.broadcast_to(jnp.arange(R, dtype=jnp.int32)[:, None], (R, T))
+    addr = jnp.swapaxes(event_addr_t.astype(jnp.int32), -1, -2)
+    return gather(rows), gather(addr), gather(jnp.swapaxes(eff, -1, -2))
 
 
 def default_max_events(T: int, R: int, threshold: float) -> int:

@@ -8,12 +8,14 @@ Two entry points:
 
 ``synaptic_current_sparse``
     The full event-sparse path on the same [N, T, R] folded operands the
-    dense ``synray`` wrapper takes: pack the window into the compact
-    event stream (``repro.core.events``), regroup per step, compute.
-    Capacities ``max_events``/``k_cap`` are static (they size the jitted
-    program); windows that overflow them silently drop records — callers
-    that cannot prove the window fits must gate on
-    ``repro.core.events.window_stats`` and fall back to the dense path
+    dense ``synray`` wrapper takes: build the per-step [T, K] record grid
+    straight from the window (``repro.core.events.pack_regrouped``, the
+    records ``pack_events`` then ``regroup_events`` would give, with no
+    scatter), compute. Capacities ``max_events``/``k_cap`` are static
+    (they size the jitted program); windows that overflow them silently
+    drop records, the same ones the stream would — callers that cannot
+    prove the window fits must gate on ``repro.core.events.window_stats``
+    and fall back to the dense path
     (``repro.core.synapse.synaptic_current_window(sparse="auto")`` does).
 
 Operands may carry an arbitrary instance prefix via the callers' fold
@@ -59,13 +61,8 @@ def sparse_window(rows_tk, addr_tk, eff_tk, weights, addresses,
 
 @functools.partial(jax.jit, static_argnames=("max_events", "k_cap"))
 def _pack_regroup(row_events_t, event_addr_t, *, max_events, k_cap):
-    T = row_events_t.shape[1]
-
-    def one(ev, ad):
-        stream = ev_mod.pack_events(ev, ad, max_events)
-        return ev_mod.regroup_events(stream, T, k_cap)
-
-    return jax.vmap(one)(row_events_t, event_addr_t)
+    return ev_mod.pack_regrouped(row_events_t, event_addr_t, max_events,
+                                 k_cap)
 
 
 def synaptic_current_sparse(row_events_t, event_addr_t, weights, addresses,

@@ -1,9 +1,9 @@
 """Pure-jnp oracle for the synray_sparse kernel — and the CPU hot path.
 
 ``sparse_window_ref`` consumes the per-step [T, K] regrouped event records
-(``repro.core.events.regroup_events``): gather each step's fired weight
-rows, apply the 6-bit address match per gathered record, and contract the
-K record slots against the efficacies.
+(``repro.core.events.pack_regrouped``, the records of ``regroup_events``):
+gather each step's fired weight rows, apply the 6-bit address match per
+gathered record, and contract the K record slots against the efficacies.
 
 Bit-exactness contract (the reason this path may replace the dense one):
 XLA:CPU reduces a contraction as one in-order FMA chain per output

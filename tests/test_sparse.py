@@ -275,6 +275,58 @@ class TestSparseBitExact:
                                       np.asarray(outs[1]))
 
 
+class TestPackRegrouped:
+    """The sparse route's scatter-free packing against the stream form it
+    replaces: the same [T, K] records bit for bit, including windows that
+    overflow either capacity (the divergence contract below depends on
+    which records are dropped)."""
+
+    T, R = 128, 64
+
+    @staticmethod
+    def _stream_form(ev, ad, max_events, k_cap):
+        T = ev.shape[-2]
+
+        def one(e, a):
+            return events.regroup_events(
+                events.pack_events(e, a, max_events), T, k_cap)
+
+        return one(ev, ad) if ev.ndim == 2 else jax.vmap(one)(ev, ad)
+
+    @pytest.mark.parametrize("prefix", [(), (3,)], ids=["single", "n3"])
+    @pytest.mark.parametrize("caps", [(656, 16), (64, 4), (T * R, R)],
+                             ids=["s5", "tight", "lossless"])
+    @pytest.mark.parametrize("p", DENSITIES)
+    def test_bit_identical_to_stream_form(self, p, caps, prefix):
+        max_events, k_cap = caps
+        shape = (*prefix, self.T, self.R)
+        ks = jax.random.split(jax.random.PRNGKey(int(p * 1000) + k_cap), 3)
+        fired = jax.random.uniform(ks[0], shape) < p
+        ev = jnp.where(fired, jax.random.uniform(ks[1], shape, minval=0.1,
+                                                 maxval=1.5), 0.0)
+        ad = jax.random.randint(ks[2], shape, 0, 64, jnp.int8)
+        want = self._stream_form(ev, ad, max_events, k_cap)
+        got = jax.jit(events.pack_regrouped, static_argnums=(2, 3))(
+            ev, ad, max_events, k_cap)
+        for name, w, g in zip(("rows", "addr", "eff"), want, got):
+            w, g = np.asarray(w), np.asarray(g)
+            assert g.dtype == w.dtype and g.shape == w.shape, name
+            if name == "eff":
+                w, g = w.view(np.uint32), g.view(np.uint32)
+            np.testing.assert_array_equal(g, w, err_msg=name)
+
+    def test_sparse_route_lowers_without_scatter(self):
+        """TPU scatters serialize: the sparse route's packing must lower
+        with none (the stream form it replaced lowers with several)."""
+        ev = jax.ShapeDtypeStruct((2, 256, 128), jnp.float32)
+        ad = jax.ShapeDtypeStruct((2, 256, 128), jnp.int8)
+        text = sparse_ops._pack_regroup.lower(
+            ev, ad, max_events=656, k_cap=16).as_text()
+        assert "scatter" not in text
+        stream = jax.jit(lambda e, a: self._stream_form(e, a, 656, 16))
+        assert "scatter" in stream.lower(ev, ad).as_text()
+
+
 class TestOverflowContract:
     """Undersized capacities must never produce silently wrong numbers.
     (Sized above ``SPARSE_MIN_DENSE_WORK`` so the "auto" cases reach the
