@@ -86,7 +86,12 @@ class AnnCore:
     address on each row never changes (each driver row carries a single
     source, as in the §5 experiment wiring). Lets the fused CPU path
     resolve the address-match mask once per window into an effective
-    weight matrix instead of re-deriving it per step.
+    weight matrix instead of re-deriving it per step (and the CPU gate's
+    default sizing, ``synapse.default_sparse_threshold``); on the TPU
+    nothing reads it. A row that receives
+    routed events breaks it (the §5 wafer's relay rows carry address 0
+    from the inputs and 63 from the bus), so ``hybrid.make_experiment``
+    makes the promise only for plans without relay rows.
     ``block_size``/``trace_block``/``kernel_block``: time-block sizes of
     the "blocked" backend (membrane scan slab, current-trace slab, and
     the Pallas kernel's VMEM-resident block).

@@ -263,9 +263,6 @@ def make_experiment(cfg: BSS2Config = None, ecfg: RSTDPConfig = RSTDPConfig(),
             cadc_gain=_cols(inst_g["cadc_gain"]))
     else:
         inst = sample_instance(cfg, instance_key, prefix)
-    # const_addr: every driver row carries exactly one source here (input i
-    # -> rows 2i/2i+1, address 0 throughout), so the fused path may resolve
-    # the address-match mask once per trial
     block_kw = {k: v for k, v in dict(
         block_size=block_size, trace_block=trace_block,
         kernel_block=kernel_block, sparse_mode=sparse_mode,
@@ -296,8 +293,15 @@ def make_experiment(cfg: BSS2Config = None, ecfg: RSTDPConfig = RSTDPConfig(),
                                  link_mode=link_mode, faults=overlay)
     else:
         router = None
+    # const_addr: a row driven by the inputs alone carries one source (input
+    # i -> rows 2i/2i+1, address 0 throughout), so the CPU's dense path may
+    # resolve the address-match mask once per trial. A plan that delivers
+    # into rows breaks the promise: the relay rows carry address 0 on
+    # external events and the route's address (63) on relayed spikes, in
+    # different steps of one trial.
+    const_addr = plan is None or not plan.relay_rows().any()
     core = AnnCore(chip_cfg, inst, backend=backend, kernel_impl=kernel_impl,
-                   const_addr=True, faults=overlay, **block_kw)
+                   const_addr=const_addr, faults=overlay, **block_kw)
     ppu = VectorUnit(chip_cfg, inst, faults=overlay)
 
     def init(key) -> ExperimentState:

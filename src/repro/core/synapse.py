@@ -55,20 +55,39 @@ def synaptic_current(weights, addresses, row_events, event_addr, gain):
 # and the static sparse cost is O(T * k_cap * C) — 0.05 keeps that well
 # under the dense work while covering the ~4-5x regime at p <= 5%.
 SPARSE_THRESHOLD = 0.05
-# With ``const_addr`` the dense alternative is the once-resolved PLAIN
-# matmul — no [T, R, C] address-mask materialization — so the sparse
-# path must clear a lower bar before it wins. "auto" therefore sizes
-# its default capacities from this lower threshold when const_addr is
-# set: windows in the (0.02, 0.05] density band that used to route
-# sparse now overflow the tighter budget and take the (cheaper-here)
-# dense fallback. Regression:
+# With ``const_addr`` the CPU's dense alternative is the once-resolved
+# PLAIN matmul — no [T, R, C] address-mask materialization — so the
+# sparse path must clear a lower bar before it wins: windows in the
+# (0.02, 0.05] density band overflow the tighter budget and take the
+# (cheaper-here) dense fallback. Regression:
 # tests/test_sparse.py::TestAutoGate::test_const_addr_lowers_crossover.
+# The kernels' gate (``pallas``, ``interpret``) is sized at this value
+# whatever the promise says (``default_sparse_threshold``).
 SPARSE_THRESHOLD_CONST_ADDR = 0.02
 # Static work floor (T * R * C MACs): below it the dense matmul is so
 # cheap that packing overhead and the runtime branch can never pay off,
 # so sparse="auto" compiles to the pure dense program (keeps e.g. the
 # 16 x 16 §5 experiment byte-for-byte the same program as before).
 SPARSE_MIN_DENSE_WORK = 2 * 1024 * 1024
+
+
+def default_sparse_threshold(impl: str, const_addr: bool) -> float:
+    """The "auto" gate's density threshold when the caller gives none.
+
+    Both values are crossovers read on the CPU against the dense window
+    the gate falls back to there: ``SPARSE_THRESHOLD`` against the
+    broadcasting oracle, ``SPARSE_THRESHOLD_CONST_ADDR`` against the
+    once-resolved matmul that ``const_addr`` allows. The kernels match
+    addresses per step whatever the promise says, and their gate has not
+    been tuned on the chip: it is sized at ``SPARSE_THRESHOLD_CONST_ADDR``,
+    the sizing the chip benchmark's cells run, for every caller. So on
+    the TPU ``const_addr`` sets nothing.
+    """
+    if impl == "auto":
+        impl = "pallas" if jax.default_backend() == "tpu" else "ref"
+    if impl == "ref" and not const_addr:
+        return SPARSE_THRESHOLD
+    return SPARSE_THRESHOLD_CONST_ADDR
 
 
 def _dense_window(weights, addresses, row_events_t, event_addr_t, gain,
@@ -170,9 +189,14 @@ def synaptic_current_window(weights, addresses, row_events_t, event_addr_t,
 
     ``const_addr=True`` asserts the event address on each row is the same
     at every step of the window (true whenever each driver row carries a
-    single source, e.g. the §5 experiment). The address-match mask is then
-    resolved ONCE into an effective weight matrix and the whole window is
-    a plain [T, R] x [R, C] matmul — no [T, R, C] mask materialization.
+    single source, e.g. the single-chip §5 experiment; false on the §5
+    wafer's relay rows, which carry address 0 from the inputs and 63 from
+    the bus). On the CPU (``impl="ref"``) the address-match mask is then
+    resolved ONCE, from the window's first step, into an effective weight
+    matrix and the whole window is a plain [T, R] x [R, C] matmul — no
+    [T, R, C] mask materialization; a broken promise gives wrong currents
+    there. The kernels and the sparse route match addresses per step
+    whatever it says, so on the TPU nothing reads it.
 
     The machine is event-driven, and at low firing rates the dense matmul
     does orders of magnitude more MACs than the events justify. ``sparse``
@@ -194,11 +218,12 @@ def synaptic_current_window(weights, addresses, row_events_t, event_addr_t,
 
     ``sparse_threshold`` sizes the default capacities: ``max_events`` ~
     threshold * T * R total records and ``k_cap`` per-step records, both
-    overridable. Its default is ``const_addr``-aware: ``SPARSE_THRESHOLD``
-    normally, the lower ``SPARSE_THRESHOLD_CONST_ADDR`` when the dense
-    alternative is the once-resolved plain matmul — the auto gate then
-    hands the (0.02, 0.05] density band back to dense, where the
-    const_addr matmul wins. ``impl`` selects the
+    overridable. Its default is ``default_sparse_threshold``: on the CPU
+    ``SPARSE_THRESHOLD``, or the lower ``SPARSE_THRESHOLD_CONST_ADDR``
+    where the dense alternative is the once-resolved plain matmul — the
+    auto gate then hands the (0.02, 0.05] density band back to dense,
+    where the const_addr matmul wins; for the kernels always the lower
+    one. ``impl`` selects the
     kernel implementation for whichever path runs (auto | pallas |
     interpret | ref). As convenience aliases, ``impl="dense"`` /
     ``impl="sparse"`` force the respective path with auto kernels.
@@ -235,10 +260,9 @@ def synaptic_current_window(weights, addresses, row_events_t, event_addr_t,
             return i
         return i, obs_trace.count_route(telemetry, sparse=False)
 
-    if sparse_threshold is not None:
-        thr = sparse_threshold
-    else:
-        thr = SPARSE_THRESHOLD_CONST_ADDR if const_addr else SPARSE_THRESHOLD
+    thr = sparse_threshold
+    if thr is None:
+        thr = default_sparse_threshold(impl, const_addr)
     if max_events is None:
         max_events = events.default_max_events(T, R, thr)
     if k_cap is None:

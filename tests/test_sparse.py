@@ -468,9 +468,11 @@ class TestAutoGate:
                 < int(n) <= synapse.SPARSE_THRESHOLD * self.T * self.R), \
             "regime check: density must sit between the two thresholds"
 
+        # the CPU's crossover: the kernels' gate ignores the promise
+        # (test_kernels_gate_ignores_promise)
         def run(const_addr):
             return synapse.synaptic_current_window(
-                w, a, ev, ad, 1.0, impl=KERNEL_IMPL, const_addr=const_addr,
+                w, a, ev, ad, 1.0, impl="ref", const_addr=const_addr,
                 sparse="auto", telemetry=obs_trace.init_telemetry())
 
         i_gen, tl_gen = jax.jit(lambda: run(False))()
@@ -487,6 +489,37 @@ class TestAutoGate:
         # is fixed, so repeated runs stay bit-identical)
         np.testing.assert_allclose(np.asarray(i_gen), np.asarray(i_ca),
                                    rtol=1e-4, atol=1e-4)
+
+    @pytest.mark.parametrize("impl,const_addr,thr", [
+        ("ref", True, synapse.SPARSE_THRESHOLD_CONST_ADDR),
+        ("ref", False, synapse.SPARSE_THRESHOLD),
+        ("interpret", True, synapse.SPARSE_THRESHOLD_CONST_ADDR),
+        ("interpret", False, synapse.SPARSE_THRESHOLD_CONST_ADDR),
+        ("pallas", False, synapse.SPARSE_THRESHOLD_CONST_ADDR)])
+    def test_kernels_gate_ignores_promise(self, impl, const_addr, thr):
+        """The default threshold follows the dense window the gate falls
+        back to: only the CPU's broadcasting oracle takes the higher one;
+        the kernels match addresses per step, and size their gate alike
+        with or without the promise."""
+        assert synapse.default_sparse_threshold(impl, const_addr) == thr
+
+    def test_kernels_gate_routes_alike_without_promise(self):
+        """At a density between the two thresholds the interpreted
+        kernels hand the window back to dense with or without the
+        promise, bit-identically."""
+        from repro.obs import trace as obs_trace
+        w, a, ev, ad = self._operands(p=0.03)
+
+        def run(const_addr):
+            return synapse.synaptic_current_window(
+                w, a, ev, ad, 1.0, impl="interpret", const_addr=const_addr,
+                sparse="auto", telemetry=obs_trace.init_telemetry())
+
+        i_gen, tl_gen = jax.jit(lambda: run(False))()
+        i_ca, tl_ca = jax.jit(lambda: run(True))()
+        assert int(tl_gen.overflow_fallbacks) == 1
+        assert int(tl_ca.overflow_fallbacks) == 1
+        np.testing.assert_array_equal(np.asarray(i_gen), np.asarray(i_ca))
 
     def test_explicit_threshold_still_wins(self):
         """A caller-provided sparse_threshold overrides the const_addr
